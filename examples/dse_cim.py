@@ -379,4 +379,6 @@ def _tpu_main(args) -> int:
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -15,9 +15,12 @@ jax twins in this package:
     (:mod:`repro.core.accel.pallas_ops`) for the segment-reduce steps.
 
 The numpy implementations stay in place as the reference oracle: the jax
-path is *differentially tested* against them (``tests/test_accel.py``)
-and every consumer falls back to numpy silently when jax is unavailable
-or the trace exceeds the int32 address budget.
+path is *differentially tested* against them (``tests/test_accel.py``).
+A trace beyond the kernels' int32 address budget is the one case that
+still takes the numpy path under ``EVA_CIM_ACCEL=jax``; each such
+fallback is counted (:func:`fallbacks`, exported by the DSE service as
+``accel.fallbacks``), so a run that was meant to stay on the device can
+check that it did.
 
 Backend selection
 -----------------
@@ -40,6 +43,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import threading
 from typing import List, Optional
 
 BACKENDS = ("numpy", "jax")
@@ -47,6 +51,8 @@ ENV_VAR = "EVA_CIM_ACCEL"
 
 _override: Optional[str] = None
 _JITTED: List[object] = []                 # jitted fns, for compile counting
+_fallback_lock = threading.Lock()
+_fallbacks = 0                             # jax -> numpy fallbacks so far
 
 
 def backend() -> str:
@@ -95,49 +101,54 @@ def jit_compiles() -> int:
     A repeated sweep over the same workloads/geometries must leave this
     number unchanged — the service's warm-path test asserts exactly that
     through the ``accel.jit_compiles`` metric."""
-    total = 0
-    for fn in _JITTED:
-        try:
-            total += int(fn._cache_size())
-        except Exception:  # noqa: BLE001 — older jax without _cache_size
-            pass
-    return total
+    return sum(int(fn._cache_size()) for fn in _JITTED)
+
+
+def fallbacks() -> int:
+    """jax -> numpy fallbacks so far in this process: calls made under the
+    jax backend that the kernels declined (int32 address overflow)."""
+    with _fallback_lock:
+        return _fallbacks
+
+
+def _counted(out):
+    """Pass a kernel result through, counting a declined call."""
+    global _fallbacks
+    if out is None:
+        with _fallback_lock:
+            _fallbacks += 1
+    return out
 
 
 def replay_columns(addrs, is_writes, geometries):
     """Batched replay under the active backend; ``None`` means "use the
-    numpy oracle" (backend is numpy, jax missing, or address overflow)."""
+    numpy oracle" (backend is numpy, or a counted address overflow)."""
     if not enabled():
         return None
-    try:
-        from repro.core.accel.replay import replay_columns_batch
-    except ImportError:
-        return None
     from repro import obs
+    from repro.core.accel.replay import replay_columns_batch
     if obs.tracer() is None:               # keep the untraced launch bare
-        return replay_columns_batch(addrs, is_writes, geometries)
+        return _counted(replay_columns_batch(addrs, is_writes, geometries))
     before = jit_compiles()
     with obs.span("accel.replay_batch", cat="jit",
                   n_geometries=len(geometries),
                   n_accesses=int(len(addrs))) as sp:
         out = replay_columns_batch(addrs, is_writes, geometries)
         sp.set(jit_compiles=jit_compiles() - before)
-        return out
+        return _counted(out)
 
 
 def place_candidates(part, ct, cfg):
-    """Jax placement under the active backend; ``None`` → numpy oracle."""
+    """Jax placement under the active backend; ``None`` → numpy oracle
+    (backend is numpy, or a counted address overflow)."""
     if not enabled():
         return None
-    try:
-        from repro.core.accel.place import place_candidates_jax
-    except ImportError:
-        return None
     from repro import obs
+    from repro.core.accel.place import place_candidates_jax
     if obs.tracer() is None:               # hot per-config path: one read
-        return place_candidates_jax(part, ct, cfg)
+        return _counted(place_candidates_jax(part, ct, cfg))
     before = jit_compiles()
     with obs.span("accel.place", cat="jit") as sp:
         out = place_candidates_jax(part, ct, cfg)
         sp.set(jit_compiles=jit_compiles() - before)
-        return out
+        return _counted(out)

@@ -23,7 +23,8 @@ the home bank.  This module runs the same math as one jitted kernel:
     ``EVA_CIM_PALLAS=1`` forces them — interpret mode on CPU).
 
 ``place_candidates_jax`` returns ``None`` whenever the trace exceeds the
-int32 budget; the caller then falls back to the numpy oracle.
+int32 budget; the caller then falls back to the numpy oracle and counts
+the fallback (:func:`repro.core.accel.fallbacks`).
 """
 from __future__ import annotations
 
@@ -31,13 +32,9 @@ import functools
 import os
 from typing import List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
-
-try:
-    import jax
-    import jax.numpy as jnp
-except ImportError:                        # pragma: no cover - jax is baked in
-    jax = None
 
 from repro.core.accel import register_jitted
 from repro.core.isa import LEVEL_MEM
@@ -145,8 +142,6 @@ def place_candidates_jax(part, ct, cfg) -> Optional[List]:
     """``_place`` on the jax backend; ``None`` -> use the numpy oracle."""
     from repro.core.offload import _DEPTH_LEVEL, _LEVEL_DEPTH, Candidate
 
-    if jax is None:
-        return None
     protos = part.protos
     if not protos:
         return []

@@ -41,14 +41,10 @@ from __future__ import annotations
 import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
-
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-except ImportError:                        # pragma: no cover - jax is baked in
-    jax = None
+from jax import lax
 
 from repro.core.accel import register_jitted
 from repro.core.cache import LINE, CacheConfig
@@ -198,19 +194,12 @@ def _build(L: int, S: int, A: int, M: int):
     return register_jitted(fn)
 
 
-# lint: numpy-twin(repro.core.cache:CacheHierarchy.replay, batched)
-def replay_columns_batch(addrs, is_writes,
-                         geometries: Sequence[Tuple[CacheConfig, ...]]
-                         ) -> Optional[List[tuple]]:
-    """Replay one access stream under every geometry in one batched call.
-
-    Returns, per geometry, ``(level, hit, bank, mshr, counters)`` — the
-    four columns of :meth:`CacheHierarchy.replay` (same dtypes) plus the
-    :meth:`CacheHierarchy.counters` dict.  Returns ``None`` when jax is
-    unavailable or the stream exceeds the int32 budget of the kernel
-    (the caller falls back to the numpy oracle)."""
-    if jax is None or not geometries:
-        return None
+def _launches(addrs, is_writes,
+              geometries: Sequence[Tuple[CacheConfig, ...]]
+              ) -> Optional[List[Tuple[List[int], object, tuple]]]:
+    """The kernel launches of one batched replay: per hierarchy depth,
+    ``(geometry indices, jitted kernel, argument tuple)``.  ``None`` when
+    the stream exceeds the kernel's int32 budget."""
     addrs = np.asarray(addrs, np.int64)
     n = addrs.shape[0]
     lines = addrs // LINE
@@ -227,10 +216,11 @@ def replay_columns_batch(addrs, is_writes,
     valid = np.zeros(n_pad, bool)
     valid[:n] = True
 
-    results: List[Optional[tuple]] = [None] * len(geometries)
     by_depth: Dict[int, List[int]] = {}
     for gi, levels in enumerate(geometries):
         by_depth.setdefault(len(levels), []).append(gi)
+
+    out = []
     for L, idxs in sorted(by_depth.items()):
         g_pad = _pow2(len(idxs))
         rows = idxs + [idxs[-1]] * (g_pad - len(idxs))   # pad with a repeat
@@ -241,10 +231,32 @@ def replay_columns_batch(addrs, is_writes,
                                     cfg.mshrs)
         fn = _build(L, _pow2(params[0].max()), _pow2(params[1].max()),
                     _pow2(params[3].max()))
-        out = fn(params[0], params[1], params[2], params[3],
-                 lines_p, wr_p, valid)
+        out.append((idxs, fn, (params[0], params[1], params[2], params[3],
+                               lines_p, wr_p, valid)))
+    return out
+
+
+# lint: numpy-twin(repro.core.cache:CacheHierarchy.replay, batched)
+def replay_columns_batch(addrs, is_writes,
+                         geometries: Sequence[Tuple[CacheConfig, ...]]
+                         ) -> Optional[List[tuple]]:
+    """Replay one access stream under every geometry in one batched call.
+
+    Returns, per geometry, ``(level, hit, bank, mshr, counters)`` — the
+    four columns of :meth:`CacheHierarchy.replay` (same dtypes) plus the
+    :meth:`CacheHierarchy.counters` dict.  Returns ``None`` when the
+    stream exceeds the int32 budget of the kernel (the caller falls back
+    to the numpy oracle and counts the fallback)."""
+    if not geometries:
+        return []
+    launches = _launches(addrs, is_writes, geometries)
+    if launches is None:
+        return None
+    n = len(addrs)
+    results: List[Optional[tuple]] = [None] * len(geometries)
+    for idxs, fn, args in launches:
         service, merged, bank, hits, misses, wbs, memr, memw = \
-            [np.asarray(o) for o in out]
+            [np.asarray(o) for o in fn(*args)]
         for r, gi in enumerate(idxs):
             levels = geometries[gi]
             codes = np.asarray([LEVEL_CODE[c.name] for c in levels]

@@ -38,7 +38,8 @@ import numpy as np
 
 from repro.core.isa import OPS, OP_CODE, OP_LOAD, OP_STORE, SRC_IMM, SRC_REG
 from repro.core.trace import (Machine, StructuralTrace, TraceInterpreter,
-                              TraceLimits, Value, _dtype_tag, _itemsize)
+                              TraceLimits, Value, _dtype_tag, _itemsize,
+                              on_host)
 
 _OP_AGEN = OP_CODE["agen"]
 _OP_BRANCH = OP_CODE["branch"]
@@ -825,7 +826,8 @@ def skim_program(fn, *args, interval: int, n_regs: int = 24) -> SkimResult:
     interp = SamplingInterpreter(m)
     arg_vals = [m.store_const(np.asarray(a))
                 for a in jax.tree_util.tree_leaves(args)]
-    interp.run(closed.jaxpr, closed.consts, arg_vals)
+    with on_host():
+        interp.run(closed.jaxpr, closed.consts, arg_vals)
     return SkimResult(features=m.features(), total_virtual=m.virtual,
                       interval=interval)
 
@@ -849,7 +851,8 @@ def trace_windows(fn, *args, windows: Sequence[Tuple[int, int]],
     interp = SamplingInterpreter(m)
     arg_vals = [m.store_const(np.asarray(a))
                 for a in jax.tree_util.tree_leaves(args)]
-    outs = interp.run(closed.jaxpr, closed.consts, arg_vals)
+    with on_host():
+        outs = interp.run(closed.jaxpr, closed.consts, arg_vals)
     marks = m.finish_marks()
     if expect_total is not None and m.virtual != expect_total:
         raise AssertionError(

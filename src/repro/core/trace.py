@@ -81,6 +81,18 @@ def _jitted_scatter(is_add: bool, dnums, mode):
     op = jax.lax.scatter_add if is_add else jax.lax.scatter
     return jax.jit(functools.partial(op, dimension_numbers=dnums, mode=mode))
 
+
+def on_host():
+    """Context that runs the VM's jax value oracles (gather/scatter,
+    dot_general, pad, sort, select_n) on jax's CPU device.
+
+    The VM simulates a host CPU, and its workloads branch on data, so an
+    oracle's value can change the committed instruction stream.  On a TPU
+    the default device would compute them with its own defaults (matmul
+    precision among them); pinning them to the CPU keeps the stream the
+    same on every platform.  Every interpreter run enters this context."""
+    return jax.default_device(jax.devices("cpu")[0])
+
 # ======================================================================
 # Values: concrete data + an address map (None => immediate / generated)
 # ======================================================================
@@ -909,7 +921,7 @@ class TraceInterpreter:
         if prim == "dot_general":
             dnums = params["dimension_numbers"]
             A, B = np.asarray(invals[0].data), np.asarray(invals[1].data)
-            out = jax.lax.dot_general(A, B, dnums)  # shape/value oracle (on CPU)
+            out = jax.lax.dot_general(A, B, dnums)  # value oracle (on_host)
             out = np.asarray(out, dtype=eqn.outvars[0].aval.dtype)
             return [self._dot_general(invals[0], invals[1], dnums, out)]
 
@@ -1229,7 +1241,8 @@ def trace_structural(fn: Callable, *args, n_regs: int = 24,
     interp = TraceInterpreter(machine)
     arg_vals = [machine.store_const(np.asarray(a))
                 for a in jax.tree_util.tree_leaves(args)]
-    outs = interp.run(closed.jaxpr, closed.consts, arg_vals)
+    with on_host():
+        outs = interp.run(closed.jaxpr, closed.consts, arg_vals)
     return StructuralTrace(machine.b.finish(machine.n_regs),
                            [np.asarray(v.data) for v in outs])
 

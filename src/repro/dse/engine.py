@@ -392,6 +392,18 @@ class AnalysisCache:
 _WORKER_CACHES: Dict[Tuple[Optional[str], Optional[int]], AnalysisCache] = {}
 
 
+def _worker_init() -> None:
+    """Process-pool initializer: keep the worker's jax on the CPU.
+
+    An accelerator belongs to one process, and the coordinator that
+    spawned the pool may hold it; a worker that opened it would fail or
+    hang.  Runs before any task, so before the worker's jax picks a
+    backend (environment too, for anything the worker starts)."""
+    import jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _worker_chunk(points: Sequence[SweepPoint], host: HostModel,
                   backend: AnalysisBackend,
                   store_root: Optional[str] = None,
@@ -451,7 +463,8 @@ class DSEEngine:
         key is built exactly once *globally*, including across repeated
         ``run()`` calls.  Spawn semantics apply: call it from a real
         module (under ``if __name__ == "__main__":`` in scripts), not
-        stdin.
+        stdin.  Workers run jax on the CPU only: an accelerator stays
+        with the coordinating process.
       * ``"serial"`` — no pool at all; useful for debugging and exact
         cost accounting.
 
@@ -553,7 +566,8 @@ class DSEEngine:
                 # spawn, not fork: the parent holds live jax/XLA threads
                 ctx = multiprocessing.get_context("spawn")
                 with ProcessPoolExecutor(max_workers=self.max_workers,
-                                         mp_context=ctx) as pool:
+                                         mp_context=ctx,
+                                         initializer=_worker_init) as pool:
                     futs = [pool.submit(_worker_chunk, c, self.host,
                                         self.backend, str(store.root),
                                         store.version, trace_ctx)

@@ -2,4 +2,6 @@
 from repro.dse.service.server import main
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

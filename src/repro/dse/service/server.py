@@ -315,7 +315,8 @@ class DSEService:
             doc["cache"][name] = layers
         from repro.core import accel
         doc["accel"] = {"backend": accel.backend(),
-                        "jit_compiles": accel.jit_compiles()}
+                        "jit_compiles": accel.jit_compiles(),
+                        "fallbacks": accel.fallbacks()}
         t = obs.tracer()
         with self._trace_lock:
             buffered = len(self._traces)

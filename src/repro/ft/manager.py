@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from repro.ckpt.checkpoint import CheckpointManager
+from repro.launch.mesh import make_mesh
 
 
 class StragglerMonitor:
@@ -62,8 +63,7 @@ def elastic_remesh(min_model_parallel: int = 1):
     mp = min_model_parallel
     while n % mp:
         mp -= 1
-    return jax.make_mesh((n // mp, mp), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    return make_mesh((n // mp, mp), ("data", "model"))
 
 
 @dataclasses.dataclass
@@ -86,10 +86,12 @@ class FaultTolerantRunner:
             step_fn: Callable[[Any, Any], Tuple[Any, Dict]],
             batch_at: Callable[[int], Any],
             *, on_failure: Optional[Callable[[int, Exception], None]] = None,
+            on_step: Optional[Callable[[int, Dict[str, float]], None]] = None,
             log_every: int = 10,
             fail_at: Optional[int] = None) -> Tuple[Any, RunReport]:
         """Run ``total_steps`` with auto-resume.  ``fail_at`` injects one
-        synthetic failure (tests/examples exercise the recovery path)."""
+        synthetic failure (tests/examples exercise the recovery path);
+        ``on_step(step, metrics)`` observes every completed step."""
         resumed_from, state = self.ckpt.restore_latest(state)
         start = 0 if resumed_from is None else resumed_from + 1
         failures = 0
@@ -116,6 +118,8 @@ class FaultTolerantRunner:
                 step = 0 if resumed is None else resumed + 1
                 continue
             dt = time.perf_counter() - t0
+            if on_step is not None:
+                on_step(step, metrics)
             if self.monitor.observe(step, dt) and log_every:
                 print(f"[ft] straggler at step {step}: {dt:.3f}s", flush=True)
             self.ckpt.maybe_save(step, state)

@@ -6,13 +6,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` appeared after
-    0.4.x — request Auto axes where supported, plain mesh otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axes (sharding propagated by XLA)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -22,7 +18,10 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(model_parallel: int = 1):
-    """Small mesh over whatever devices exist (tests / examples)."""
+    """(data, model) mesh over every device of this host; the data axis
+    takes what the model axis leaves."""
     n = len(jax.devices())
-    mp = model_parallel if n % model_parallel == 0 else 1
-    return make_mesh((n // mp, mp), ("data", "model"))
+    if model_parallel < 1 or n % model_parallel:
+        raise ValueError(f"model_parallel={model_parallel} does not divide "
+                         f"the {n} devices of this host")
+    return make_mesh((n // model_parallel, model_parallel), ("data", "model"))

@@ -20,7 +20,9 @@ from repro.models.transformer import init_params
 from repro.train import steps as steps_mod
 
 
-def main(argv=None) -> int:
+def run(argv=None) -> np.ndarray:
+    """Serve as the command line says; returns the generated tokens,
+    ``(batch, gen)`` int32 (the prefill's token, then one per decode)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gemma3-1b", choices=sorted(ARCHS))
     ap.add_argument("--preset", default="smoke", choices=["smoke", "full"])
@@ -58,14 +60,21 @@ def main(argv=None) -> int:
         tok.block_until_ready()
         t_decode = time.perf_counter() - t0
 
-    gen = jnp.concatenate(outs, axis=1)
+    gen = np.asarray(jnp.concatenate(outs, axis=1))
     tps = args.batch * (args.gen - 1) / max(t_decode, 1e-9)
     print(f"[serve] arch={args.arch} batch={args.batch} "
           f"prefill={t_prefill*1e3:.1f}ms "
           f"decode={t_decode*1e3:.1f}ms ({tps:.1f} tok/s)", flush=True)
-    print(f"[serve] sample tokens: {np.asarray(gen[0][:16])}", flush=True)
+    print(f"[serve] sample tokens: {gen[0][:16]}", flush=True)
+    return gen
+
+
+def main(argv=None) -> int:
+    run(argv)
     return 0
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     raise SystemExit(main())

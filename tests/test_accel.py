@@ -21,10 +21,9 @@ backends interchangeable:
   * the batched ``attach_cache_results_batch`` vs geometry-at-a-time
     numpy attachment.
 
-Strategies stick to the integers/sampled_from/lists surface so the
-conftest hypothesis fallback sampler can drive them; geometry parameters
-are drawn from a small fixed pool so the jit cache stays bounded (shapes
-are padded to powers of two — see ``repro.core.accel.replay``).
+Geometry parameters are drawn from a small fixed pool so the jit cache
+stays bounded (shapes are padded to powers of two — see
+``repro.core.accel.replay``).
 """
 import jax
 import jax.numpy as jnp
@@ -283,6 +282,20 @@ def test_jit_compile_accounting():
     out = replay_columns_batch(addrs + 64, ~wr, geos)
     assert out is not None
     assert accel.jit_compiles() == before
+
+
+def test_fallback_counter_on_int32_overflow():
+    """A stream beyond the int32 line budget is one counted fallback under
+    the jax backend, and none under numpy (which never tries jax)."""
+    addrs = np.asarray([0, 2 ** 40], np.int64)
+    wr = np.zeros(2, bool)
+    before = accel.fallbacks()
+    with accel.use_backend("jax"):
+        assert accel.replay_columns(addrs, wr, [GEOMETRIES[0]]) is None
+    assert accel.fallbacks() == before + 1
+    with accel.use_backend("numpy"):
+        assert accel.replay_columns(addrs, wr, [GEOMETRIES[0]]) is None
+    assert accel.fallbacks() == before + 1
 
 
 def test_replay_overflow_falls_back():

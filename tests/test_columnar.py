@@ -3,8 +3,7 @@
 The columnar trace core re-derives everything the object-based pipeline
 used to build incrementally — RUT/IHT, the producer index, the flow maps,
 the IDG forest, the candidate partition — vectorized from the columns.
-These tests drive random small jaxpr programs (hypothesis, or the conftest
-fallback sampler) plus the three Fig. 4 pattern variants through BOTH
+These tests drive random small jaxpr programs (hypothesis) plus the three Fig. 4 pattern variants through BOTH
 paths and require identical results:
 
   * the ``Inst`` row views are faithful to the columns, and reconstructing

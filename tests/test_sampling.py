@@ -1,6 +1,6 @@
 """repro.core.sampling: spec codecs, plan construction, the windowed
 trace machinery's byte-identity against the exact VM, estimator
-unbiasedness (property tests — hypothesis, or the conftest seeded shim),
+unbiasedness (hypothesis property tests),
 the degenerate full-coverage plan reproducing exact metrics bit-for-bit,
 sampled sweep records through the engine/backend, and request-codec
 validation of the ``sampling`` field."""

@@ -401,6 +401,7 @@ def test_jax_backend_zero_recompiles(daemon):
 
     _url, client, _service = daemon
     req = dict(caches=["32K+256K", "64K+256K", "64K+2M"], techs=["sram"])
+    fallbacks = accel.fallbacks()
     with accel.use_backend("jax"):
         client.sweep(["KM"], **req)                    # cold: compiles
         m1 = client.metrics()
@@ -412,6 +413,7 @@ def test_jax_backend_zero_recompiles(daemon):
         client.sweep(["KM"], **req)                    # warm repeat
         m2 = client.metrics()
         assert m2["accel"]["jit_compiles"] == compiles
+        assert m2["accel"]["fallbacks"] == fallbacks   # stayed on jax
         assert (m2["cache"]["cim"]["replay_batches"]
                 == m1["cache"]["cim"]["replay_batches"])
 
