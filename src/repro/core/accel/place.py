@@ -36,10 +36,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.accel import register_jitted
 from repro.core.isa import LEVEL_MEM
 
 _I32_LIM = 2 ** 31 - 1
+SCOPE = "eva_cim.place"            # named scope of the kernel's ops
 
 
 def _pow2(n: int) -> int:
@@ -72,31 +74,36 @@ def _build(n_leaf: int, n_acc: int, n_seg_pad: int,
         def seg_max(v, i):
             return jax.ops.segment_max(v, i, num_segments=n_seg_pad)
 
+    # the jitted function's name gives the device trace its module,
+    # ``jit_kernel``; the named scope marks every op of the body
     def kernel(leaf_level, leaf_pid, acc_level, acc_line, acc_pid, n_seg):
-        # target level: deepest leaf (DRAM clamped to the cap), lifted to
-        # the shallowest enabled depth; empty segments place at depth 0,
-        # exactly like the numpy path's zero-filled max_depth
-        depth = jnp.minimum(leaf_level - 1, depth_cap)
-        max_depth = jnp.maximum(seg_max(depth, leaf_pid), 0)
-        tpos = jnp.minimum(jnp.searchsorted(enabled_arr, max_depth),
-                           len(enabled) - 1)
-        target = enabled_arr[tpos]
+        with jax.named_scope(SCOPE):
+            # target level: deepest leaf (DRAM clamped to the cap), lifted
+            # to the shallowest enabled depth; empty segments place at
+            # depth 0, exactly like the numpy path's zero-filled max_depth
+            depth = jnp.minimum(leaf_level - 1, depth_cap)
+            max_depth = jnp.maximum(seg_max(depth, leaf_pid), 0)
+            tpos = jnp.minimum(jnp.searchsorted(enabled_arr, max_depth),
+                               len(enabled) - 1)
+            target = enabled_arr[tpos]
 
-        # moves: leaves resident shallower than the target level
-        shallower = (depth < target[leaf_pid]).astype(jnp.int32)
-        moves = seg_sum(shallower, leaf_pid)
+            # moves: leaves resident shallower than the target level
+            shallower = (depth < target[leaf_pid]).astype(jnp.int32)
+            moves = seg_sum(shallower, leaf_pid)
 
-        # DRAM fills: unique (proto, line) pairs among MEM-served accesses;
-        # sort by (proto, line) and count group heads (sentinel-segment
-        # entries — non-MEM accesses and padding — are masked out)
-        pid_k = jnp.where(acc_level == LEVEL_MEM, acc_pid, n_seg)
-        order = jnp.lexsort((acc_line, pid_k))
-        sp = pid_k[order]
-        sl = acc_line[order]
-        head = jnp.concatenate([jnp.ones(1, bool),
-                                (sp[1:] != sp[:-1]) | (sl[1:] != sl[:-1])])
-        fills = seg_sum((head & (sp < n_seg)).astype(jnp.int32), sp)
-        return target, moves, fills
+            # DRAM fills: unique (proto, line) pairs among MEM-served
+            # accesses; sort by (proto, line) and count group heads
+            # (sentinel-segment entries — non-MEM accesses and padding —
+            # are masked out)
+            pid_k = jnp.where(acc_level == LEVEL_MEM, acc_pid, n_seg)
+            order = jnp.lexsort((acc_line, pid_k))
+            sp = pid_k[order]
+            sl = acc_line[order]
+            head = jnp.concatenate([jnp.ones(1, bool),
+                                    (sp[1:] != sp[:-1])
+                                    | (sl[1:] != sl[:-1])])
+            fills = seg_sum((head & (sp < n_seg)).astype(jnp.int32), sp)
+            return target, moves, fills
 
     return register_jitted(jax.jit(kernel))
 
@@ -145,39 +152,44 @@ def place_candidates_jax(part, ct, cfg) -> Optional[List]:
     protos = part.protos
     if not protos:
         return []
-    leaf_seq, leaf_pid, acc_seq, acc_pid = _flat_arrays(part, ct, cfg)
     n_seg = len(protos)
-    acc_addr = ct.addr[acc_seq]
-    # int32 budget guard over the *real* access rows only: padding rows
-    # carry the sentinel pid and gather ct.addr[0], which is -1 whenever
-    # seq 0 is not a memory access — the kernel masks them out, so they
-    # must not veto the jax path
-    real_addr = acc_addr[acc_pid < n_seg]
-    if len(real_addr) and (real_addr.min() < 0
-                           or real_addr.max() // 64 >= _I32_LIM):
-        return None
-    depth_cap = max(_LEVEL_DEPTH[l] for l in cfg.cim_levels)
-    enabled = tuple(sorted(_LEVEL_DEPTH[l] for l in cfg.cim_levels))
-    fn = _build(len(leaf_seq), len(acc_seq), _pow2(n_seg + 1),
-                enabled, depth_cap, _use_pallas())
-    target, moves, fills = fn(
-        ct.level[leaf_seq].astype(np.int32), leaf_pid,
-        ct.level[acc_seq].astype(np.int32),
-        (acc_addr // 64).astype(np.int32), acc_pid, np.int32(n_seg))
-    target = np.asarray(target)[:n_seg]
-    moves = np.asarray(moves)[:n_seg]
-    fills = np.asarray(fills)[:n_seg]
+    with obs.span("accel.place.pack", cat="jit") as sp:
+        leaf_seq, leaf_pid, acc_seq, acc_pid = _flat_arrays(part, ct, cfg)
+        sp.set(n_leaf=len(leaf_seq), n_acc=len(acc_seq), n_seg=n_seg)
+        acc_addr = ct.addr[acc_seq]
+        # int32 budget guard over the *real* access rows only: padding
+        # rows carry the sentinel pid and gather ct.addr[0], which is -1
+        # whenever seq 0 is not a memory access — the kernel masks them
+        # out, so they must not veto the jax path
+        real_addr = acc_addr[acc_pid < n_seg]
+        if len(real_addr) and (real_addr.min() < 0
+                               or real_addr.max() // 64 >= _I32_LIM):
+            return None
+        args = (ct.level[leaf_seq].astype(np.int32), leaf_pid,
+                ct.level[acc_seq].astype(np.int32),
+                (acc_addr // 64).astype(np.int32), acc_pid, np.int32(n_seg))
+        depth_cap = max(_LEVEL_DEPTH[l] for l in cfg.cim_levels)
+        enabled = tuple(sorted(_LEVEL_DEPTH[l] for l in cfg.cim_levels))
+        fn = _build(len(leaf_seq), len(acc_seq), _pow2(n_seg + 1),
+                    enabled, depth_cap, _use_pallas())
+    # dispatch, the device run and the copy back
+    with obs.span("accel.place.device", cat="jit"):
+        target, moves, fills = fn(*args)
+        target = np.asarray(target)[:n_seg]
+        moves = np.asarray(moves)[:n_seg]
+        fills = np.asarray(fills)[:n_seg]
 
-    bank_col = ct.bank
-    level_of = [_DEPTH_LEVEL[int(d)] for d in target]
-    out = []
-    for i, p in enumerate(protos):
-        out.append(Candidate(
-            root_seq=p.root_seq, op_seqs=p.op_seqs, op_classes=p.op_classes,
-            load_seqs=p.load_seqs, store_seqs=p.store_seqs,
-            level=level_of[i],
-            bank=int(bank_col[p.load_seqs[0]]) if p.load_seqs else None,
-            moves=int(moves[i]), internal_edges=p.internal_edges,
-            added_loads=p.added_loads, memval_leaves=p.memval_leaves,
-            dram_fills=int(fills[i])))
+    with obs.span("accel.place.unpack", cat="jit", n_candidates=n_seg):
+        bank_col = ct.bank
+        level_of = [_DEPTH_LEVEL[int(d)] for d in target]
+        out = []
+        for i, p in enumerate(protos):
+            out.append(Candidate(
+                root_seq=p.root_seq, op_seqs=p.op_seqs,
+                op_classes=p.op_classes, load_seqs=p.load_seqs,
+                store_seqs=p.store_seqs, level=level_of[i],
+                bank=int(bank_col[p.load_seqs[0]]) if p.load_seqs else None,
+                moves=int(moves[i]), internal_edges=p.internal_edges,
+                added_loads=p.added_loads, memval_leaves=p.memval_leaves,
+                dram_fills=int(fills[i])))
     return out

@@ -51,6 +51,7 @@ from repro.core.cache import LINE, CacheConfig
 from repro.core.isa import LEVEL_CODE, LEVEL_MEM
 
 _I32_LIM = 2 ** 31 - 1
+STEP_SCOPE = "eva_cim.replay.step"       # named scope of one scan step's ops
 
 
 def _pow2(n: int) -> int:
@@ -112,7 +113,7 @@ def _build(L: int, S: int, A: int, M: int):
                 jnp.where(en, stamp_val, row_s[way]))
             return victim, victim_line, tags, dirty, stamp
 
-        def step(carry, x):
+        def access(carry, x):
             tags, dirty, stamp, mlines, mstamp, wbs, memw, t = carry
             line, wr, ok = x
             base = t * K
@@ -173,6 +174,10 @@ def _build(L: int, S: int, A: int, M: int):
             bank = line % banks[jnp.minimum(service, jnp.int32(L)) - 1]
             return ((tags, dirty, stamp, mlines, mstamp, wbs, memw, t + 1),
                     (service, merged, bank))
+
+        def step(carry, x):
+            with jax.named_scope(STEP_SCOPE):
+                return access(carry, x)
 
         init = (jnp.full((L, S, A), -1, jnp.int32),
                 jnp.zeros((L, S, A), jnp.bool_),

@@ -45,6 +45,7 @@ from typing import (Dict, FrozenSet, Iterator, List, Optional, Sequence, Set,
 
 import numpy as np
 
+from repro import obs
 from repro.core.columnar import ColumnarTrace
 from repro.core.idg import (LEAF_IMM, LEAF_LOAD, LEAF_MEMVAL, FlowIndex,
                             IDGBuilder, IDGNode, build_flow_index)
@@ -475,15 +476,17 @@ def _try_accept_structural(node: _SeqNode, flow: FlowIndex, op_col: List[int],
 
 
 def _partition(ct: ColumnarTrace, builder: IDGBuilder, flow: FlowIndex,
-               cfg: OffloadConfig) -> SelectionPartition:
+               cfg: OffloadConfig, sp=obs.NULL_SPAN) -> SelectionPartition:
     """Algorithm 1's reverse-order tree extraction, structural fields only.
 
     Memoized per (structural trace, partition key) on the trace's shared
     ``_struct`` dict — one partition serves every geometry and CiM level
-    set of a sweep."""
+    set of a sweep.  ``sp``, the caller's span, is told whether the memo
+    answered and how many proto-candidates came out."""
     memo = ct._struct.setdefault("partitions", {})
     hit = memo.get(cfg.partition_key())
     if hit is not None:
+        sp.set(source="memo", n_protos=len(hit.protos))
         return hit
     from repro.core.idg import _tables
     from repro.core.isa import OP_CODE, OP_LOAD, OP_MOV
@@ -522,6 +525,7 @@ def _partition(ct: ColumnarTrace, builder: IDGBuilder, flow: FlowIndex,
     protos.reverse()                         # report in program order
     part = SelectionPartition(protos, claimed)
     memo[cfg.partition_key()] = part
+    sp.set(source="build", n_protos=len(protos))
     return part
 
 
@@ -674,7 +678,8 @@ def select_candidates(trace: Trace, rut=None, iht=None,
     if isinstance(trace, ColumnarTrace):
         if cfg.allow_cross_level and not cfg.require_same_bank:
             # structural partition (shared across geometries) + placement
-            part = _partition(trace, builder, flow, cfg)
+            with obs.span("select.partition", cat="select") as sp:
+                part = _partition(trace, builder, flow, cfg, sp)
             return OffloadResult(_place(part, trace, cfg), part.claimed,
                                  flow, cfg)
         # placement-dependent acceptance: single-pass over CiM roots only

@@ -15,6 +15,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.cache import CacheConfig, CacheHierarchy
 from repro.core.columnar import ColumnarTrace
 from repro.core.device_model import (DRAM_ACCESS_PJ, DRAM_LATENCY_CYCLES,
@@ -316,9 +317,12 @@ def profile_system(tr: TraceResult,
         result = select_candidates(trace, tr.rut, tr.iht, offload_cfg)
     reshaped = reshaped or reshape(trace, result)
     prof = Profiler(cache_cfgs, tech=tech, host=host)
-    base_eb, base_cycles = prof.price_baseline(trace)
-    cim_eb, cim_cycles = prof.price_cim(trace, reshaped)
-    mb = result.macr_breakdown(trace)
+    with obs.span("price.baseline", cat="price"):
+        base_eb, base_cycles = prof.price_baseline(trace)
+    with obs.span("price.cim", cat="price"):
+        cim_eb, cim_cycles = prof.price_cim(trace, reshaped)
+    with obs.span("price.macr", cat="price"):
+        mb = result.macr_breakdown(trace)
     return SystemReport(
         base=base_eb, cim=cim_eb,
         base_cycles=base_cycles, cim_cycles=cim_cycles,

@@ -312,7 +312,9 @@ class AnalysisCache:
                 sp.set(source="build", layer=2)
                 analysis = self.trace_analysis(workload, cache)
                 result = analysis.select(cfg)
-                reshaped = reshape(analysis.trace, result)
+                with obs.span("select.reshape", cat="select") as rsp:
+                    reshaped = reshape(analysis.trace, result)
+                    rsp.set(n_host_seqs=len(reshaped.host_seqs))
                 with self._lock:
                     self._offloads[key] = (result, reshaped)
                 if self.store is not None:
@@ -439,7 +441,7 @@ def _worker_chunk(points: Sequence[SweepPoint], host: HostModel,
                           workload=points[0].workload,
                           n_points=len(points), pid=os.getpid()):
                 records = [backend.evaluate(cache, p, host) for p in points]
-        spans, _ = worker_tracer.drain()
+        spans = worker_tracer.drain()
     else:
         records = [backend.evaluate(cache, p, host) for p in points]
     delta = {k: v - before.get(k, 0) for k, v in cache.stats().items()

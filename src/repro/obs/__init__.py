@@ -19,7 +19,7 @@ Tracing is off by default and free when off::
 See ``docs/architecture.md`` ("Tracing") for the span taxonomy.
 """
 from repro.obs.tracer import (NULL_SPAN, Span, TraceContext, Tracer, active,
-                              attach, counter, current, disable, enable,
+                              attach, current, disable, enable,
                               ingest, span, tracer)
 from repro.obs.export import (attribution_markdown, build_tree,
                               export_chrome, export_ndjson,
@@ -27,7 +27,7 @@ from repro.obs.export import (attribution_markdown, build_tree,
 
 __all__ = [
     "NULL_SPAN", "Span", "TraceContext", "Tracer",
-    "active", "attach", "counter", "current", "disable", "enable",
+    "active", "attach", "current", "disable", "enable",
     "ingest", "span", "tracer",
     "attribution_markdown", "build_tree", "export_chrome",
     "export_ndjson", "stage_attribution",

@@ -1,6 +1,6 @@
 """Exporters + rollups over finished span dicts.
 
-Everything here is a pure function over the span/counter dicts a
+Everything here is a pure function over the span dicts a
 :class:`repro.obs.Tracer` collects (see ``tracer.py`` for the record
 shape), so it works equally on a live tracer's buffer, a daemon ring
 buffer entry, or spans re-read from an NDJSON log.
@@ -9,22 +9,24 @@ Three consumers, three formats:
 
 * :func:`export_chrome` — Chrome trace-event JSON, the dialect
   Perfetto (https://ui.perfetto.dev) and ``chrome://tracing`` load:
-  complete spans as ``ph:"X"`` events (``ts``/``dur`` in microseconds),
-  counter samples as ``ph:"C"`` events, and ``ph:"M"`` metadata naming
+  complete spans as ``ph:"X"`` events (``ts``/``dur`` in microseconds)
+  and ``ph:"M"`` metadata naming
   each pid/tid so the track labels read "eva-cim (pid 1234)" /
   "dse-worker-3" instead of bare numbers.
 * :func:`export_ndjson` — one span dict per line, for grep/jq.
 * :func:`stage_attribution` — the per-stage rollup behind
   ``examples/dse_cim.py --trace-report``: total and *self* time per
-  category (self = duration minus children, clamped at zero — so with a
-  serial executor the self times of a trace telescope back to its root
-  span's duration), cache hit ratios from ``source=`` attributes, and a
-  per-workload breakdown.  :func:`attribution_markdown` renders it.
+  category (self = duration minus the union of the children's
+  intervals, so children that overlap on a thread pool are not
+  subtracted twice, and with a serial executor the self times of a trace
+  telescope back to its root span's duration), cache hit ratios from
+  ``source=`` attributes, and a per-workload breakdown.
+  :func:`attribution_markdown` renders it.
 """
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, Iterable, List, Sequence, Tuple
 
 # span attrs tagging how a cache layer answered; memo/store count as
 # hits (work reused), build as a miss, coalesced as a dedup'd wait
@@ -36,8 +38,8 @@ def _us(ns: int) -> float:
     return ns / 1000.0
 
 
-def export_chrome(spans: Sequence[Dict], counters: Sequence[Dict] = (),
-                  path: Any = None, name: str = "eva-cim") -> int:
+def export_chrome(spans: Sequence[Dict], path: Any,
+                  name: str = "eva-cim") -> int:
     """Write Chrome trace-event JSON; returns the number of X events.
 
     ``path`` may be a filesystem path or an open text file.  Timestamps
@@ -45,9 +47,7 @@ def export_chrome(spans: Sequence[Dict], counters: Sequence[Dict] = (),
     unix-epoch microseconds fine, but a zero origin keeps the numbers
     readable in the JSON itself)."""
     events: List[Dict] = []
-    base_ns = min([s["ts_ns"] for s in spans]
-                  + [c["ts_ns"] for c in counters]) if (spans or counters) \
-        else 0
+    base_ns = min((s["ts_ns"] for s in spans), default=0)
     seen_pids: Dict[int, None] = {}
     seen_tids: Dict[tuple, str] = {}
     for s in spans:
@@ -63,13 +63,6 @@ def export_chrome(spans: Sequence[Dict], counters: Sequence[Dict] = (),
                        "ph": "X", "ts": _us(s["ts_ns"] - base_ns),
                        "dur": _us(s["dur_ns"]), "pid": pid, "tid": tid,
                        "args": args})
-    n_span_events = len(events)
-    for c in counters:
-        pid = c["pid"]
-        seen_pids.setdefault(pid, None)
-        events.append({"name": c["name"], "cat": "counter", "ph": "C",
-                       "ts": _us(c["ts_ns"] - base_ns), "pid": pid,
-                       "tid": 0, "args": {"value": c["value"]}})
     meta: List[Dict] = []
     for pid in seen_pids:
         meta.append({"name": "process_name", "ph": "M", "pid": pid,
@@ -78,13 +71,13 @@ def export_chrome(spans: Sequence[Dict], counters: Sequence[Dict] = (),
         meta.append({"name": "thread_name", "ph": "M", "pid": pid,
                      "tid": tid, "args": {"name": tname}})
     doc = {"traceEvents": meta + events, "displayTimeUnit": "ms",
-           "otherData": {"producer": "repro.obs", "spans": n_span_events}}
+           "otherData": {"producer": "repro.obs", "spans": len(events)}}
     if hasattr(path, "write"):
         json.dump(doc, path)
     else:
         with open(path, "w") as fh:
             json.dump(doc, fh)
-    return n_span_events
+    return len(events)
 
 
 def export_ndjson(spans: Sequence[Dict], path: Any) -> int:
@@ -121,6 +114,22 @@ def build_tree(spans: Sequence[Dict]) -> List[Dict]:
     return roots
 
 
+def _union_ns(intervals: Iterable[Tuple[int, int]]) -> int:
+    """Length of the union of ``(start, end)`` intervals."""
+    total = 0
+    cur_s = cur_e = None
+    for lo, hi in sorted(intervals):
+        if cur_e is None or lo > cur_e:
+            if cur_e is not None:
+                total += cur_e - cur_s
+            cur_s, cur_e = lo, hi
+        elif hi > cur_e:
+            cur_e = hi
+    if cur_e is not None:
+        total += cur_e - cur_s
+    return total
+
+
 def stage_attribution(spans: Sequence[Dict]) -> Dict:
     """Per-stage (span category) rollup of where the time went.
 
@@ -136,11 +145,18 @@ def stage_attribution(spans: Sequence[Dict]) -> Dict:
          "workloads": {workload: {cat: self_s}}}
     """
     by_id = {s["span_id"]: s for s in spans}
-    children_ns: Dict[str, int] = {}
+    # each child's interval, clipped to its parent's; the union of a
+    # span's children is what it did not do itself
+    child_iv: Dict[str, List[Tuple[int, int]]] = {}
     for s in spans:
-        parent = s.get("parent_id")
-        if parent and parent in by_id:
-            children_ns[parent] = children_ns.get(parent, 0) + s["dur_ns"]
+        parent = by_id.get(s.get("parent_id"))
+        if parent is None:
+            continue
+        lo = max(s["ts_ns"], parent["ts_ns"])
+        hi = min(s["ts_ns"] + s["dur_ns"],
+                 parent["ts_ns"] + parent["dur_ns"])
+        if hi > lo:
+            child_iv.setdefault(parent["span_id"], []).append((lo, hi))
 
     stages: Dict[str, Dict] = {}
     workloads: Dict[str, Dict[str, float]] = {}
@@ -149,7 +165,8 @@ def stage_attribution(spans: Sequence[Dict]) -> Dict:
     for s in spans:
         if not (s.get("parent_id") and s["parent_id"] in by_id):
             wall_ns += s["dur_ns"]
-        self_ns = max(0, s["dur_ns"] - children_ns.get(s["span_id"], 0))
+        self_ns = max(0, s["dur_ns"]
+                      - _union_ns(child_iv.get(s["span_id"], ())))
         attributed_ns += self_ns
         cat = s["cat"] or "misc"
         st = stages.setdefault(cat, {"count": 0, "total_ns": 0,

@@ -41,7 +41,7 @@ import os
 import threading
 import time
 import uuid
-from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence
 
 _current: contextvars.ContextVar[Optional["TraceContext"]] = \
     contextvars.ContextVar("eva_cim_trace_ctx", default=None)
@@ -125,7 +125,7 @@ class Span:
 
 
 class Tracer:
-    """Collector of finished spans + counter samples for one process."""
+    """Collector of finished spans for one process."""
 
     def __init__(self, name: str = "eva-cim", max_spans: int = 200_000):
         self.name = name
@@ -137,7 +137,6 @@ class Tracer:
         self._seq = itertools.count()         # next() is atomic in CPython
         self._lock = threading.Lock()
         self._spans: List[Dict] = []          # lint: guarded-by(_lock)
-        self._samples: List[Dict] = []        # lint: guarded-by(_lock)
         self.dropped = 0                      # lint: guarded-by(_lock)
 
     # ------------------------------------------------------------- spans
@@ -169,42 +168,25 @@ class Tracer:
             else:
                 self._spans.append(rec)
 
-    # ----------------------------------------------------------- counters
-    def counter(self, name: str, value: float) -> None:
-        """Record one counter sample (a Chrome ``C`` event on export)."""
-        sample = {"name": name, "value": float(value),
-                  "ts_ns": time.perf_counter_ns() + self._epoch_ns,
-                  "pid": self.pid}
-        with self._lock:
-            if len(self._samples) < self.max_spans:
-                self._samples.append(sample)
-
     # ------------------------------------------------------------- access
     def spans(self) -> List[Dict]:
         with self._lock:
             return list(self._spans)
 
-    def counters(self) -> List[Dict]:
-        with self._lock:
-            return list(self._samples)
-
-    def ingest(self, spans: Iterable[Dict],
-               samples: Iterable[Dict] = ()) -> None:
+    def ingest(self, spans: Iterable[Dict]) -> None:
         """Adopt finished spans shipped from another tracer (typically a
         process-pool worker's :meth:`drain`) — already absolute-timed and
         pid-stamped, so they merge without translation."""
-        spans, samples = list(spans), list(samples)
+        spans = list(spans)
         with self._lock:
             self._spans.extend(spans)
-            self._samples.extend(samples)
 
-    def drain(self) -> Tuple[List[Dict], List[Dict]]:
-        """Remove and return everything collected so far."""
+    def drain(self) -> List[Dict]:
+        """Remove and return every span collected so far."""
         with self._lock:
-            spans, samples = self._spans, self._samples
+            spans = self._spans
             self._spans = []
-            self._samples = []
-            return spans, samples
+            return spans
 
     def take(self, trace_id: str) -> List[Dict]:
         """Remove and return the finished spans of one trace (the DSE
@@ -221,7 +203,7 @@ class Tracer:
         """Write a Chrome trace-event JSON file (Perfetto-loadable);
         returns the number of span events written."""
         from repro.obs import export
-        return export.export_chrome(self.spans(), self.counters(), path)
+        return export.export_chrome(self.spans(), path)
 
     def export_ndjson(self, path) -> int:
         from repro.obs import export
@@ -268,12 +250,6 @@ def span(name: str, cat: str = "misc", **attrs):
     return t.span(name, cat, **attrs)
 
 
-def counter(name: str, value: float) -> None:
-    t = _tracer
-    if t is not None:
-        t.counter(name, value)
-
-
 def current() -> Optional[TraceContext]:
     """The pickle-able propagation handle for the active span (``None``
     when tracing is off or no span is open)."""
@@ -307,8 +283,8 @@ def attach(ctx: Optional[TraceContext]) -> _Attach:
     return _Attach(ctx)
 
 
-def ingest(spans: Sequence[Dict], samples: Sequence[Dict] = ()) -> None:
+def ingest(spans: Sequence[Dict]) -> None:
     """Adopt worker-shipped spans into the installed tracer, if any."""
     t = _tracer
-    if t is not None and (spans or samples):
-        t.ingest(spans, samples)
+    if t is not None and spans:
+        t.ingest(spans)

@@ -313,3 +313,26 @@ def test_replay_overflow_falls_back():
     for col in ("level", "hit", "bank", "mshr"):
         assert np.array_equal(getattr(ref.trace, col),
                               getattr(out.trace, col))
+
+
+def test_kernel_names_are_stable_in_the_lowered_modules():
+    """The device-trace reduction finds the placement kernel by its
+    module, ``jit_kernel``, and a profile tells the replay's scan steps
+    apart by their named scope: both survive in the lowered text."""
+    import re
+    from repro.core.accel import place, replay
+
+    i32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.int32)
+    fn = place._build.__wrapped__(64, 64, 16, (0, 1), 1, False)
+    lowered = fn.lower(i32(64), i32(64), i32(64), i32(64), i32(64),
+                       jax.ShapeDtypeStruct((), jnp.int32))
+    module = re.search(r"module @(\S+)", lowered.as_text()).group(1)
+    assert module.startswith("jit_kernel")
+    assert f"{place.SCOPE}/" in lowered.as_text(debug_info=True)
+
+    fn = replay._build.__wrapped__(2, 64, 8, 8)
+    params = [jax.ShapeDtypeStruct((4, 2), jnp.int32)] * 4
+    stream = [jax.ShapeDtypeStruct((128,), dt)
+              for dt in (jnp.int32, jnp.bool_, jnp.bool_)]
+    text = fn.lower(*params, *stream).as_text(debug_info=True)
+    assert f"{replay.STEP_SCOPE}/" in text

@@ -78,7 +78,7 @@ def test_off_hands_out_the_shared_null_span_and_records_nothing():
     assert s is obs.NULL_SPAN and s is obs.span("z")
     with s as entered:
         assert entered.set(a=1) is entered
-    obs.counter("c", 1.0)                      # all no-ops
+    obs.ingest([{"name": "w"}])                # all no-ops
     assert obs.current() is None
     with obs.attach(None):                     # no-op attach
         assert obs.current() is None
@@ -102,11 +102,9 @@ def test_take_removes_one_trace_and_drain_empties():
     taken = t.take(sa.trace_id)
     assert [s["name"] for s in taken] == ["a"]
     assert [s["name"] for s in t.spans()] == ["b"]
-    t.counter("c", 2.0)
-    spans, samples = t.drain()
+    spans = t.drain()
     assert [s["name"] for s in spans] == ["b"]
-    assert [c["name"] for c in samples] == ["c"]
-    assert t.spans() == [] and t.counters() == []
+    assert t.spans() == []
 
 
 def test_enable_keeps_installed_tracer_unless_given_one():
@@ -128,6 +126,47 @@ def test_engine_records_identical_tracing_on_vs_off():
     names = {s["name"] for s in t.spans()}
     assert {"dse.run", "cache.trace", "cache.select",
             "backend.evaluate"} <= names
+
+
+# the phase spans inside selection and pricing: name -> its parent
+_PHASES = {"select.partition": "cache.select",
+           "select.reshape": "cache.select",
+           "accel.place.pack": "accel.place",
+           "accel.place.device": "accel.place",
+           "accel.place.unpack": "accel.place",
+           "price.baseline": "backend.price",
+           "price.cim": "backend.price",
+           "price.macr": "backend.price"}
+
+
+def test_accelerated_sweep_emits_phase_spans_under_their_layers():
+    from repro.core import accel
+    space = _space()
+    with accel.use_backend("jax"):
+        base = DSEEngine(executor="serial").run(space)
+        t = obs.enable(obs.Tracer())
+        traced = DSEEngine(executor="serial").run(space)
+    assert [dataclasses.astuple(r) for r in traced] == \
+        [dataclasses.astuple(r) for r in base]
+    spans = t.spans()
+    by_id = {s["span_id"]: s for s in spans}
+    seen = {}
+    for s in spans:
+        if s["name"] in _PHASES:
+            assert by_id[s["parent_id"]]["name"] == _PHASES[s["name"]]
+            seen.setdefault(s["name"], []).append(s["attrs"])
+    assert set(seen) == set(_PHASES)
+    for attrs in seen["select.partition"]:
+        assert attrs["source"] in ("memo", "build") and attrs["n_protos"] > 0
+    for attrs in seen["accel.place.pack"]:
+        assert attrs["n_leaf"] >= attrs["n_seg"] > 0 and attrs["n_acc"] > 0
+    for attrs in seen["accel.place.unpack"]:
+        assert attrs["n_candidates"] > 0
+    for attrs in seen["select.reshape"]:
+        assert attrs["n_host_seqs"] > 0
+    # one pricing phase of each kind per point
+    assert all(len(seen[n]) == len(traced)
+               for n in ("price.baseline", "price.cim", "price.macr"))
 
 
 def test_serial_attribution_telescopes_to_wall_clock():
@@ -189,7 +228,6 @@ def test_adaptive_rounds_emit_spans():
 def test_chrome_export_is_perfetto_valid(tmp_path):
     t = obs.enable(obs.Tracer())
     DSEEngine(executor="serial").run(_space())
-    obs.counter("points", 4.0)
     path = tmp_path / "trace.json"
     n = t.export_chrome(path)
     doc = json.loads(path.read_text())
@@ -202,8 +240,7 @@ def test_chrome_export_is_perfetto_valid(tmp_path):
                 "args"} <= set(e)
         assert e["ts"] >= 0 and e["dur"] >= 0
     # timestamps rebase to a zero origin
-    assert min(e["ts"] for e in events if e["ph"] in "XC") == \
-        pytest.approx(0.0)
+    assert min(e["ts"] for e in xs) == pytest.approx(0.0)
     # every child's [ts, ts+dur] nests inside its parent's interval
     by_id = {e["args"]["span_id"]: e for e in xs}
     for e in xs:
@@ -212,8 +249,7 @@ def test_chrome_export_is_perfetto_valid(tmp_path):
             p = by_id[ref]
             assert e["ts"] >= p["ts"] - 1e-3
             assert e["ts"] + e["dur"] <= p["ts"] + p["dur"] + 1e-3
-    cs = [e for e in events if e["ph"] == "C"]
-    assert cs and all("value" in e["args"] for e in cs)
+    assert {e["ph"] for e in events} == {"X", "M"}
     names = {e["name"] for e in events if e["ph"] == "M"}
     assert {"process_name", "thread_name"} <= names
 
@@ -260,6 +296,18 @@ def test_stage_attribution_orphans_count_as_roots():
     att = obs.stage_attribution([_synth("x", "missing", "trace", 0, 50)])
     assert att["wall_s"] == pytest.approx(50e-9)
     assert att["coverage"] == pytest.approx(1.0)
+
+
+def test_stage_attribution_unites_overlapping_children():
+    # two pool threads price 10..60 and 40..90 under one 0..100 run: the
+    # run did 20 ns itself (the union covers 80), not 0 (the sum is 100)
+    spans = [_synth("run", None, "engine", 0, 100),
+             _synth("a", "run", "price", 10, 50),
+             _synth("b", "run", "price", 40, 50)]
+    att = obs.stage_attribution(spans)
+    assert att["stages"]["engine"]["self_s"] == pytest.approx(20e-9)
+    assert att["stages"]["price"]["self_s"] == pytest.approx(100e-9)
+    assert att["attributed_s"] == pytest.approx(120e-9)
 
 
 def test_build_tree_nests_children_and_orphans():
