@@ -167,7 +167,10 @@ class ColumnarTrace(Sequence):
     and this dict by reference): derived structural artifacts — the
     vectorized RUT/IHT tables, producer indices, flow index, selection
     partitions — are computed once per traced program however many cache
-    configurations a sweep prices.
+    configurations a sweep prices.  A variant loaded from disk starts with
+    a memo of its own; it joins another trace's memo only when its
+    structural columns equal that trace's (:meth:`same_structure`), as
+    :class:`~repro.dse.engine.AnalysisCache` checks on every store load.
     """
 
     __slots__ = ("n", "op", "unit", "dtype", "dst", "addr", "size", "level",
@@ -208,6 +211,14 @@ class ColumnarTrace(Sequence):
             self.size, level, hit, bank, mshr, self.src_off, self.src_tag,
             self.src_val, self.src_kind, self.n_regs,
             struct_cache=self._struct)
+
+    def same_structure(self, other: "ColumnarTrace") -> bool:
+        """Whether ``other`` has this trace's structural columns (every
+        column but the memory responses) and register count, value for
+        value — the condition for sharing one ``_struct`` memo."""
+        return (self.n == other.n and self.n_regs == other.n_regs
+                and all(np.array_equal(getattr(self, c), getattr(other, c))
+                        for c in _STRUCTURAL))
 
     def to_arrays(self) -> Dict[str, np.ndarray]:
         """Column dict for .npz persistence (repro.dse.store layer 1)."""

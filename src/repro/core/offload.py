@@ -662,7 +662,8 @@ def rehydrate_analysis(tr, flow: FlowIndex) -> TraceAnalysis:
     rebuilds the full analysis without re-walking the trace."""
     trace = tr.trace
     if isinstance(trace, ColumnarTrace):
-        trace._struct.setdefault("flow", flow)
+        # a trace that shares a structural memo keeps the memo's flow
+        flow = trace._struct.setdefault("flow", flow)
         return TraceAnalysis(trace, flow=flow)
     return TraceAnalysis(trace, tr.rut, tr.iht, flow=flow)
 

@@ -80,7 +80,10 @@ class AnalysisCache:
     build: misses consult the store first, and every artifact built here is
     persisted, so the build counters stay an honest measure of *global*
     analysis work — a warm store means ``trace_builds == 0`` even in a new
-    process.
+    process.  A geometry variant loaded from the store joins its
+    workload's structural memo (``ColumnarTrace._struct``) only when its
+    structural columns equal those of the structural trace already held
+    for the workload; ``struct_shared`` counts the loads that did.
     """
 
     def __init__(self, store: Optional[Union[AnalysisStore, str,
@@ -100,6 +103,7 @@ class AnalysisCache:
         self.offload_builds = 0  # lint: guarded-by(_lock)
         self.offload_hits = 0    # lint: guarded-by(_lock)
         self.replay_batches = 0  # lint: guarded-by(_lock)
+        self.struct_shared = 0   # lint: guarded-by(_lock)
 
     def _key_lock(self, key: Tuple) -> threading.Lock:
         """Per-key build lock: concurrent misses on one key build once."""
@@ -145,6 +149,42 @@ class AnalysisCache:
                 finally:
                     self._prune_lock(skey)
 
+    def _load_from_store(self, workload: str, cache: CacheOption
+                     ) -> Optional[Tuple[TraceResult, bool]]:
+        """Layer 1 of ``(workload, cache)`` from the store into the memo:
+        ``(trace, shared)``, or ``None`` on a store miss.
+
+        When the workload already has a structural trace here and the
+        loaded structural columns equal it, the loaded trace is rebuilt on
+        top of that trace (``shared`` is true): it then shares its
+        ``_struct`` memo, so the partition, IDG tables, flow and placement
+        arrays are built once per workload, not once per cache.  Unequal
+        columns keep the loaded trace and its own memo."""
+        loaded = self.store.load_layer1(workload, cache.levels)
+        if loaded is None:
+            return None
+        tr, flow = loaded
+        with self._lock:
+            known = self._structural.get(workload)
+        shared = known is not None \
+            and known.columns.same_structure(tr.trace)
+        if shared:
+            ct = tr.trace
+            tr = TraceResult(known.columns.with_mem_results(
+                ct.level, ct.hit, ct.bank, ct.mshr),
+                tr.cache, tr.outputs, structural=known)
+        key = (workload, cache.levels)
+        with self._lock:
+            self._traces[key] = tr
+            if shared:
+                self.struct_shared += 1
+            if tr.structural is not None \
+                    and workload not in self._structural:
+                self._structural[workload] = tr.structural
+            if flow is not None and key not in self._analyses:
+                self._analyses[key] = rehydrate_analysis(tr, flow)
+        return tr, shared
+
     def trace(self, workload: str, cache: CacheOption) -> TraceResult:
         key = (workload, cache.levels)             # full geometry, not name
         with obs.span("cache.trace", cat="replay", workload=workload,
@@ -157,18 +197,11 @@ class AnalysisCache:
                         sp.set(source="memo", layer=1)
                         return hit
                 if self.store is not None:
-                    loaded = self.store.load_layer1(workload, cache.levels)
+                    loaded = self._load_from_store(workload, cache)
                     if loaded is not None:
-                        tr, flow = loaded
-                        with self._lock:
-                            self._traces[key] = tr
-                            if tr.structural is not None \
-                                    and workload not in self._structural:
-                                self._structural[workload] = tr.structural
-                            if flow is not None and key not in self._analyses:
-                                self._analyses[key] = rehydrate_analysis(tr,
-                                                                         flow)
-                        sp.set(source="store", layer=1)
+                        tr, shared = loaded
+                        sp.set(source="store", layer=1,
+                               struct="shared" if shared else "own")
                         return tr
                 with self._lock:
                     self.trace_builds += 1
@@ -196,7 +229,9 @@ class AnalysisCache:
         ``replay_batches`` counts those launches.  Counter semantics match
         :meth:`trace`: memo hits bump ``trace_hits``, store loads bump
         neither, and each geometry actually replayed bumps
-        ``trace_builds``."""
+        ``trace_builds``.  The span's ``n_shared`` counts the store loads
+        that joined the workload's structural memo, and ``struct`` reads
+        ``"shared"`` when any did."""
         uniq: List[CacheOption] = []
         seen = set()
         for c in caches:
@@ -213,6 +248,7 @@ class AnalysisCache:
                       n_geometries=len(uniq)) as gsp, self._key_lock(gkey):
             try:
                 missing: List[CacheOption] = []
+                loads = []                   # store loads: shared memo or not
                 for c in uniq:
                     key = (workload, c.levels)
                     with self._lock:
@@ -220,22 +256,16 @@ class AnalysisCache:
                             self.trace_hits += 1
                             continue
                     if self.store is not None:
-                        loaded = self.store.load_layer1(workload, c.levels)
+                        loaded = self._load_from_store(workload, c)
                         if loaded is not None:
-                            tr, flow = loaded
-                            with self._lock:
-                                self._traces[key] = tr
-                                if tr.structural is not None \
-                                        and workload not in self._structural:
-                                    self._structural[workload] = tr.structural
-                                if flow is not None \
-                                        and key not in self._analyses:
-                                    self._analyses[key] = \
-                                        rehydrate_analysis(tr, flow)
+                            loads.append(loaded[1])
                             continue
                     missing.append(c)
                 gsp.set(n_replayed=len(missing),
                         source="build" if missing else "memo")
+                if loads:
+                    gsp.set(struct="shared" if any(loads) else "own",
+                            n_shared=sum(loads))
                 if not missing:
                     return
                 st = self._structural_trace(workload)
@@ -379,7 +409,8 @@ class AnalysisCache:
                "trace_hits": self.trace_hits,
                "offload_builds": self.offload_builds,
                "offload_hits": self.offload_hits,
-               "replay_batches": self.replay_batches}
+               "replay_batches": self.replay_batches,
+               "struct_shared": self.struct_shared}
         if self.store is not None:
             out.update(self.store.stats())
         return out

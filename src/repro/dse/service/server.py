@@ -312,6 +312,7 @@ class DSEService:
                     "hit_rate": (round(hits / (hits + builds), 3)
                                  if hits + builds else None)}
             layers["replay_batches"] = stats.get("replay_batches", 0)
+            layers["struct_shared"] = stats.get("struct_shared", 0)
             doc["cache"][name] = layers
         from repro.core import accel
         doc["accel"] = {"backend": accel.backend(),

@@ -169,6 +169,52 @@ def test_accelerated_sweep_emits_phase_spans_under_their_layers():
                for n in ("price.baseline", "price.cim", "price.macr"))
 
 
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_store_loads_share_the_partition_build(tmp_path, backend):
+    """Every store load of a workload after its first takes the shared
+    structural memo (``struct="shared"``), so a store-backed sweep builds
+    Algorithm 1's partition once per (workload, partition key)."""
+    from repro.core import accel
+    from repro.dse import AnalysisCache
+    space = SweepSpace(workloads=("NB", "LCS"),
+                       caches=("32K+256K", "64K+256K", "64K+2M"),
+                       cim_levels=("L1_only", "both"))
+    with accel.use_backend("numpy"):                # layer 1 only
+        fill = AnalysisCache(store=tmp_path)
+        for p in space.points():
+            fill.trace_analysis(p.workload, p.cache)
+    with accel.use_backend(backend):
+        t = obs.enable(obs.Tracer())
+        res = DSEEngine(cache=AnalysisCache(store=tmp_path),
+                        executor="thread", max_workers=1).run(space)
+    assert res.stats["trace_builds"] == 0
+    assert res.stats["struct_shared"] == 4
+    spans = t.spans()
+    by_id = {s["span_id"]: s for s in spans}
+    if backend == "numpy":                  # one cache.trace span per load
+        loads = {}
+        for s in spans:
+            if s["name"] == "cache.trace" \
+                    and s["attrs"].get("source") == "store":
+                loads.setdefault(s["attrs"]["workload"], []).append(
+                    s["attrs"]["struct"])
+        assert loads == {"NB": ["own", "shared", "shared"],
+                         "LCS": ["own", "shared", "shared"]}
+    else:                                   # one batch span per workload
+        batches = {s["attrs"]["workload"]: s["attrs"] for s in spans
+                   if s["name"] == "cache.replay_batch"}
+        assert set(batches) == {"NB", "LCS"}
+        for attrs in batches.values():
+            assert attrs["struct"] == "shared" and attrs["n_shared"] == 2
+    builds = {}
+    for s in spans:
+        if s["name"] == "select.partition" \
+                and s["attrs"]["source"] == "build":
+            wl = by_id[s["parent_id"]]["attrs"]["workload"]
+            builds[wl] = builds.get(wl, 0) + 1
+    assert builds == {"NB": 1, "LCS": 1}
+
+
 def test_serial_attribution_telescopes_to_wall_clock():
     t = obs.enable(obs.Tracer())
     DSEEngine(executor="serial").run(_space())

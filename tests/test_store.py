@@ -3,10 +3,12 @@ invalidation, corrupted-file recovery, cross-engine zero-rebuild runs,
 concurrent same-key races (one blob, consistent counters), directory
 format-marker compatibility, and backend-namespaced coexistence (CiM +
 TPU artifacts in one cache dir)."""
+import dataclasses
 import json
 import pickle
 import threading
 
+import numpy as np
 import pytest
 
 from repro.core import profile_system
@@ -402,3 +404,93 @@ def test_trace_vm_bump_misses_while_tpu_stays_warm(tmp_path):
     tpu = DSEEngine(store=bumped2, backend=TpuBackend()).run(TPU_SPACE)
     assert tpu.stats["trace_builds"] == 0
     assert tpu.stats["store_l1_hits"] == 1
+
+
+# ---------------------------------------- structural memo across store loads
+_GEOMETRIES = ("32K+256K", "64K+256K", "64K+2M")
+_SHARE_SPACE = SweepSpace(workloads=("NB",), caches=_GEOMETRIES,
+                          cim_levels=("L1_only", "both"))
+
+
+def _fill_layer1(root, caches=_GEOMETRIES):
+    """Layer 1 (trace + flow) of NB under ``caches``, on the numpy path."""
+    from repro.core import accel
+    with accel.use_backend("numpy"):
+        fill = AnalysisCache(store=AnalysisStore(root))
+        for name in caches:
+            fill.trace_analysis("NB", CacheOption.of(name))
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_store_loads_share_one_structural_memo(tmp_path, backend):
+    """Every geometry of a workload loaded from the store shares the first
+    load's ``_struct`` memo, so Algorithm 1's partition is built once per
+    workload, and the records equal those of a sweep without a store."""
+    from repro.core import accel
+    _fill_layer1(tmp_path)
+    with accel.use_backend(backend):
+        cache = AnalysisCache(store=AnalysisStore(tmp_path))
+        stored = DSEEngine(cache=cache, executor="serial").run(_SHARE_SPACE)
+        fresh = DSEEngine(executor="serial").run(_SHARE_SPACE)
+    assert stored.stats["trace_builds"] == 0
+    structs = [cache._traces[("NB", CacheOption.of(n).levels)].trace._struct
+               for n in _GEOMETRIES]
+    assert all(s is structs[0] for s in structs)
+    assert len(structs[0]["partitions"]) == 1
+    assert cache.stats()["struct_shared"] == 2
+    assert stored.stats["struct_shared"] == 2
+    assert [dataclasses.astuple(r) for r in stored] == \
+        [dataclasses.astuple(r) for r in fresh]
+
+
+def test_store_load_with_other_structure_keeps_its_own_memo(tmp_path):
+    """A stored trace whose addresses differ from the workload's known
+    structural trace in one row keeps its own memo, and its selections are
+    those of a fresh analysis of that very trace."""
+    from repro.core.columnar import ColumnarTrace
+    from repro.core.offload import analyze_trace
+    from repro.core.trace import TraceResult
+    first, other = (CacheOption.of(n) for n in _GEOMETRIES[:2])
+    _fill_layer1(tmp_path, _GEOMETRIES[:1])
+    built = AnalysisCache().trace("NB", other)
+    arrays = built.trace.to_arrays()
+    addr = arrays["col_addr"].copy()
+    row = int(np.flatnonzero(built.trace.mem_mask)[0])
+    addr[row] += 64                                  # one line further
+    arrays["col_addr"] = addr
+    planted = TraceResult(ColumnarTrace.from_arrays(arrays), built.cache,
+                          built.outputs)
+    store = AnalysisStore(tmp_path)
+    store.save_layer1("NB", other.levels, planted)
+
+    cache = AnalysisCache(store=AnalysisStore(tmp_path))
+    known = cache.trace("NB", first)
+    loaded = cache.trace("NB", other)
+    assert cache.trace_builds == 0
+    assert loaded.trace._struct is not known.trace._struct
+    assert np.array_equal(loaded.trace.addr, addr)
+    assert cache.stats()["struct_shared"] == 0
+    got, _ = cache.offload("NB", other, CFG)
+    want = analyze_trace(planted).select(CFG)
+    assert [dataclasses.astuple(c) for c in got.candidates] == \
+        [dataclasses.astuple(c) for c in want.candidates]
+    assert got.claimed == want.claimed
+    assert cache.stats()["struct_shared"] == 0
+
+
+def test_daemon_reports_struct_shared_per_cache(tmp_path):
+    """The daemon's stats document carries ``struct_shared`` under every
+    backend cache; a store-backed sweep of two geometries shares once."""
+    from repro.dse.service import ServiceClient, running_server
+    _fill_layer1(tmp_path, _GEOMETRIES[:2])
+    with running_server(cache_dir=str(tmp_path)) as (url, _service):
+        client = ServiceClient(url)
+        reply = client.sweep(["NB"], caches=list(_GEOMETRIES[:2]),
+                             techs=["sram"])
+        assert len(reply.records) == 2
+        doc = client.metrics()
+    for backend in ("cim", "tpu"):
+        assert "struct_shared" in doc["cache"][backend]
+    assert doc["cache"]["cim"]["struct_shared"] == 1
+    assert doc["cache"]["tpu"]["struct_shared"] == 0
+    assert doc["cache"]["cim"]["layer1"]["builds"] == 0
