@@ -36,7 +36,30 @@ def pow2(n: int) -> int:
     return 1 << (max(n, 64) - 1).bit_length()
 
 
-class Measurements:
+class DeviceReadings:
+    """The profiled part's device numbers (``device``: the reduced trace,
+    or None where the run caught no device) as the result line and the
+    per-layer readers take them."""
+
+    device: Optional[Dict] = None
+
+    @property
+    def busy_s(self) -> Optional[float]:
+        return None if self.device is None else self.device["busy_s"]
+
+    @property
+    def window_s(self) -> Optional[float]:
+        return None if self.device is None else self.device["window_s"]
+
+    @property
+    def breakdown(self) -> Optional[Dict]:
+        if self.device is None:
+            return None
+        return {"device_ops": self.device["device_ops"],
+                "idle_gaps": self.device["idle_gaps"]}
+
+
+class Measurements(DeviceReadings):
     """Span self-times over the whole window; device time, kernel
     launches and their costs over the profiled part of it."""
 
@@ -82,12 +105,17 @@ class Measurements:
         return out
 
     def roofline(self, kernel: str) -> Optional[float]:
+        """None where a launch's workload has no known size."""
         if kernel == "replay":
             got = [(w, s) for w, s, _ in self.replays]
+            if any(w not in self.replay_sizes for w, _ in got):
+                return None
             costs = [kernels.replay_cost(n, self.replay_sizes[w][1])
                      for w, _, n in self.replays]
         else:
             got = self.launches(kernel)
+            if any(w not in self.place_sizes for w, _ in got):
+                return None
             costs = [kernels.place_cost(*self.place_sizes[w])
                      for w, _ in got]
         total = sum(s for _, s in got)
@@ -95,21 +123,6 @@ class Measurements:
             return None
         share, _ = kernels.roofline_share(costs, total, self.peaks)
         return share
-
-    @property
-    def busy_s(self) -> Optional[float]:
-        return None if self.device is None else self.device["busy_s"]
-
-    @property
-    def window_s(self) -> Optional[float]:
-        return None if self.device is None else self.device["window_s"]
-
-    @property
-    def breakdown(self) -> Optional[Dict]:
-        if self.device is None:
-            return None
-        return {"device_ops": self.device["device_ops"],
-                "idle_gaps": self.device["idle_gaps"]}
 
 
 def replay_steps(m: Measurements) -> int:

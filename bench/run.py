@@ -3,18 +3,21 @@
 
     python3 bench/run.py --workload dse5.reselect --seed 7 --seconds 40 --trace 0
 
-The cell (``BENCHMARK.json``'s ``workloads``) names a configuration (a
-design space, ``bench/configs/``) and a traffic mix (``bench/traffic/``).
-A run checks that the default device is a TPU, draws the workloads'
-inputs from ``--seed``, fills the mix's store and warms up with one
-sweep, then runs
-``DSEEngine(executor="thread").run(space)`` sweep after sweep until
-``--seconds`` have passed; the window ends with the sweep in progress.
-After the window the records of every sweep, and the stream, replay and
-selections of one sweep drawn from the seed, are compared with the plain
-reference (:mod:`bench.check`).  ``--trace 1`` reports the per-layer metrics
-(``bench/metrics/``) from the program's spans and from a profiler trace
-of part of the window instead of the end-to-end ones.
+The cell (``BENCHMARK.json``'s ``workloads``) names a configuration
+(``bench/configs/``) and a traffic mix (``bench/traffic/``).  The
+configuration's ``kind`` names the module under ``bench/kinds/`` that runs
+it (a configuration without one is a CiM design space, ``sweep``):
+
+``sweep``   sweeps of the Eva-CiM design space through the DSE engine
+            (:mod:`bench.kinds.sweep`);
+``train``   training steps of a model through the repo's training entry
+            point (:mod:`bench.kinds.train`).
+
+A run checks that the default device is a TPU, sets up the persistent
+compile cache and the compile watch, and hands the cell to its kind, which
+sets up, warms up every shape, measures for ``--seconds`` and compares what
+the window produced with a plain reference.  ``--trace 1`` reports the
+per-layer metrics (``bench/metrics/``) instead of the end-to-end ones.
 
 The last line of standard output is one JSON object; the numbers compared
 for ``correct`` are the last lines of standard error and the last key of
@@ -27,15 +30,15 @@ import time
 T0 = time.perf_counter()
 
 import argparse
+import importlib
 import importlib.util
 import json
 import os
 import pathlib
-import random
-import resource
 import shutil
 import sys
 import threading
+from typing import Dict, Optional, Sequence
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 METRICS = ROOT / "bench" / "metrics"
@@ -126,53 +129,60 @@ class Profile(threading.Thread):
         jax.profiler.stop_trace()
 
 
-def _measurements(span_records, points, profile, observed, reference,
-                  geometries, device_kind):
-    """Spans of the window, the profiled part's device trace reduced, and
-    the sizes of every replay and placement launch, per workload."""
-    from bench import devtrace, measure
-    from bench.peaks import peak
+def device_trace(profile: Optional[Profile], span_names: Sequence[str],
+                 kernel_modules: Dict[str, str]) -> Optional[Dict]:
+    """The profiled part of the window reduced (:func:`bench.devtrace.reduce`),
+    or None where nothing was profiled or the profile caught no device.
+    The profile's files are removed."""
+    from bench import devtrace
 
-    device = None
-    if profile is not None and profile.window_ns:
-        try:
-            path = next(profile.path.rglob("*.xplane.pb"))
-            ev = devtrace.events_of(str(path), measure.SPAN_NAMES)
-            n_dev = sum(len(v) for c in ev["chips"].values()
-                        for v in c.values())
-            log(f"profile {path.stat().st_size} bytes, {n_dev} device "
-                f"events, {len(ev['host'])} host spans")
-            for chip, lines in ev["chips"].items():
-                mods = {}
-                for name, _, _ in lines[devtrace.MODULES_LINE]:
-                    head = name.split("(", 1)[0]
-                    mods[head] = mods.get(head, 0) + 1
-                starts = [s for v in lines.values() for _, s, _ in v]
-                log(f"{chip}: lines {ev['lines'].get(chip)}; "
-                    + ", ".join(f"{k} {len(v)}" for k, v in lines.items())
-                    + f"; events from {min(starts, default=None)} to "
-                    f"{max(starts, default=None)} ns; modules "
-                    + ", ".join(f"{k} x{n}" for k, n in sorted(
-                        mods.items(), key=lambda kv: -kv[1])[:6]))
-        finally:
-            shutil.rmtree(profile.path, ignore_errors=True)
-        if not ev["chips"]:
-            log("the profile holds no TPU device plane")
-            return measure.Measurements(span_records, points, None,
-                                        peak(device_kind), {}, {})
-        device = devtrace.reduce(ev, profile.window_ns,
-                                 measure.KERNEL_MODULES)
-    replay_sizes, place_sizes = {}, {}
-    for (w, c), cols in observed["traces"].items():
-        replay_sizes[w] = (len(cols[0]), list(geometries.values()))
-    for (w, c, levels) in observed["selections"]:
-        if w not in place_sizes:
-            cands, _ = reference.select(w, c, levels)
-            place_sizes[w] = (sum(x["leaves"] for x in cands),
-                              sum(len(x["load_seqs"]) + len(x["store_seqs"])
-                                  for x in cands), len(cands))
-    return measure.Measurements(span_records, points, device,
-                                peak(device_kind), replay_sizes, place_sizes)
+    if profile is None or not profile.window_ns:
+        return None
+    try:
+        path = next(profile.path.rglob("*.xplane.pb"))
+        ev = devtrace.events_of(str(path), span_names)
+        n_dev = sum(len(v) for c in ev["chips"].values() for v in c.values())
+        log(f"profile {path.stat().st_size} bytes, {n_dev} device "
+            f"events, {len(ev['host'])} host spans")
+        for chip, lines in ev["chips"].items():
+            mods = {}
+            for name, _, _ in lines[devtrace.MODULES_LINE]:
+                head = name.split("(", 1)[0]
+                mods[head] = mods.get(head, 0) + 1
+            starts = [s for v in lines.values() for _, s, _ in v]
+            log(f"{chip}: lines {ev['lines'].get(chip)}; "
+                + ", ".join(f"{k} {len(v)}" for k, v in lines.items())
+                + f"; events from {min(starts, default=None)} to "
+                f"{max(starts, default=None)} ns; modules "
+                + ", ".join(f"{k} x{n}" for k, n in sorted(
+                    mods.items(), key=lambda kv: -kv[1])[:6]))
+    finally:
+        shutil.rmtree(profile.path, ignore_errors=True)
+    if not ev["chips"]:
+        log("the profile holds no TPU device plane")
+        return None
+    return devtrace.reduce(ev, profile.window_ns, kernel_modules)
+
+
+def kind_of(config: Dict):
+    """The module under ``bench/kinds/`` that runs ``config``."""
+    return importlib.import_module(f"bench.kinds.{config.get('kind', 'sweep')}")
+
+
+def enable_compile_cache() -> None:
+    """JAX's persistent compile cache at ``<checkout>/.jax_cache``."""
+    import jax
+    sys.path.insert(0, str(ROOT / "src"))
+    cache_dir = ROOT / ".jax_cache"
+    cache_dir.mkdir(exist_ok=True)
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
+    from repro.compile_cache import enable_compile_cache as enable
+    jax.config.update("jax_compilation_cache_dir", enable())
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # no eviction: an evicting cache keeps an access-time file beside each
+    # entry, and a machine that sets a size limit then fails every write
+    jax.config.update("jax_compilation_cache_max_size", -1)
 
 
 def run(args, require_tpu: bool = True, root: pathlib.Path = ROOT) -> dict:
@@ -182,103 +192,23 @@ def run(args, require_tpu: bool = True, root: pathlib.Path = ROOT) -> dict:
     from bench import cell as cells
     bench_cell, config, traffic = cells.load_cell(args.workload, root)
     devices = devices_or_exit(bench_cell["chips"], require_tpu)
-
-    import jax
-    sys.path.insert(0, str(ROOT / "src"))
-    os.environ["EVA_CIM_ACCEL"] = "jax"
-    cache_dir = ROOT / ".jax_cache"
-    cache_dir.mkdir(exist_ok=True)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(cache_dir)
-    from repro.compile_cache import enable_compile_cache
-    jax.config.update("jax_compilation_cache_dir", enable_compile_cache())
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    # no eviction: an evicting cache keeps an access-time file beside each
-    # entry, and a machine that sets a size limit then fails every write
-    jax.config.update("jax_compilation_cache_max_size", -1)
+    enable_compile_cache()
     watch = CompileWatch()
 
-    from bench import check
-    from bench.inputs import seeded_workloads
-    from repro import obs
-    from repro.core import accel
-
+    from bench.kinds import Context
+    kind = kind_of(config)
     bench = json.loads((root / "BENCHMARK.json").read_text())
     per_layer = [m for m in bench["per_layer"]
                  if args.workload in m.get("workloads", [args.workload])]
-    space, geometries = cells.build_space(config)
-    n_points = len(space)
-    # every sweep's records are kept; one sweep, drawn from the seed, keeps
-    # its analysis cache for the replay and selection comparison, so what
-    # the harness holds does not grow with the sweeps of the window
-    draw = random.Random(args.seed)
-    records, kept = [], None
-    with seeded_workloads(config["inputs"], args.seed, config.get("scales")):
-        sweeper = cells.Sweeps(traffic, space)
-        try:
-            sweeper.fill()
-            sweeper.run()               # warms every shape the window uses
-            setup_s = time.perf_counter() - T0
-            setup_compiles = watch.count
-            log(f"set-up {setup_s:.3f} s, {setup_compiles} compilations "
-                f"({watch.seconds:.3f} s)")
+    res = kind.run(Context(args, config, traffic, devices, watch, T0, log,
+                           Profile, device_trace))
 
-            tracer = profile = None
-            if args.trace:
-                from bench.spans import annotating_tracer
-                tracer = obs.enable(annotating_tracer())
-                prof_dir = cells.WORK / "profile"
-                shutil.rmtree(prof_dir, ignore_errors=True)
-                profile = Profile(prof_dir, **traffic["profile"])
-            t_open = time.perf_counter()
-            if profile is not None:
-                profile.start()
-            while True:
-                cache, results = sweeper.run()
-                records.append(results.records)
-                if draw.randrange(len(records)) == 0:
-                    kept = (len(records) - 1, cache)
-                del cache, results
-                if time.perf_counter() - t_open >= args.seconds:
-                    break
-            window_s = time.perf_counter() - t_open
-            if profile is not None:
-                profile.join()
-            if tracer is not None:
-                obs.disable()
-        finally:
-            sweeper.close()
-    window_compiles = watch.count - setup_compiles
-    rss_gb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1e6
-    stats = devices[0].memory_stats() or {}
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices),
-              "memory_peak_bytes": stats.get("peak_bytes_in_use")}
-    points = n_points * len(records)
-    log(f"window {window_s:.3f} s, {len(records)} sweeps, {points} "
-        f"points, fallbacks {accel.fallbacks()}")
-
-    # the program's state goes before the reference runs
-    full = check.observe(kept[1], space, records[kept[0]], with_streams=True)
-    observed = [full if i == kept[0] else check.records_only(recs)
-                for i, recs in enumerate(records)]
-    del kept, records
-    streams = {w: s for w, (_, s) in full["streams"].items()}
-    reference = check.Reference(streams, geometries, config["cim_set"])
-    t_ref = time.perf_counter()
-    limits = config["limits"]
-    numbers = check.compare(observed, space, config, reference)
-    log(f"reference {time.perf_counter() - t_ref:.3f} s; window "
-        f"compilations {window_compiles}")
-    for (w, c), (*_, counters) in full["traces"].items():
-        log(f"replay {w} {c}: " + ", ".join(
-            f"{k} {counters[k]}" for k in ("L1_misses", "L1_writebacks",
-                                           "L2_hits", "L2_misses")))
-
+              "memory_peak_bytes": res.memory_peak_bytes}
     breakdown = None
     if args.trace:
-        m = _measurements(tracer.spans(), points, profile, full,
-                          reference, geometries, devices[0].device_kind)
+        m = res.measurements
         device["busy_s"], device["window_s"] = m.busy_s, m.window_s
         breakdown = m.breakdown
         readers = metric_readers(e["name"] for e in per_layer)
@@ -289,22 +219,18 @@ def run(args, require_tpu: bool = True, root: pathlib.Path = ROOT) -> dict:
                 metrics[entry["name"]] = {"value": value,
                                           "unit": entry["unit"]}
     else:
-        metrics = {
-            "points_per_s": {"value": points / window_s, "unit": "points/s"},
-            "peak_rss_gb": {"value": rss_gb, "unit": "GB"},
-            "setup_s": {"value": setup_s, "unit": "s"},
-        }
-    checks = {k: {"value": numbers[k], "limit": limits[k]}
-              for k in check.NUMBERS}
+        metrics = res.end_to_end
+    limits = config["limits"]
+    checks = {k: {"value": res.numbers[k], "limit": limits[k]}
+              for k in kind.NUMBERS}
     correct = all(v["value"] <= v["limit"] for v in checks.values())
     for k, v in checks.items():
         log(f"check {k} {v['value']!r} limit {v['limit']!r}")
-    out = {"correct": correct, "attempted": points,
-           "failed": numbers["failed_points"], "metrics": metrics,
-           "device": device}
+    out = {"correct": correct, "attempted": res.attempted,
+           "failed": res.failed, "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
-    out["window_compiles"] = window_compiles
+    out["window_compiles"] = res.window_compiles
     out["checks"] = checks
     return out
 

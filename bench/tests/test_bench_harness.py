@@ -22,6 +22,9 @@ def test_cells_find_their_config_and_traffic(name):
     entry, config, traffic = cell.load_cell(name)
     assert entry["name"] == name
     assert config["name"] == entry["config"]
+    if config.get("kind", "sweep") == "train":
+        _train_cell_holds(config, traffic)
+        return
     assert traffic["layer1"] in ("build", "store")
     space, geometries = cell.build_space(config)
     assert len(space) == (len(config["workloads"]) * len(config["caches"])
@@ -29,6 +32,40 @@ def test_cells_find_their_config_and_traffic(name):
                           * len(config["hosts"]))
     assert sorted(geometries) == sorted(c["name"] for c in config["caches"])
     assert set(config["inputs"]) == set(config["workloads"])
+
+
+def _train_cell_holds(config, traffic):
+    """The entry's command line, the tokens of a step, and the layout
+    that ties the program's parameters to the reference's."""
+    from bench import model_reference
+    from bench.kinds import train
+    argv = train.argv_of(config, traffic, "/ck")
+    e = config["entry"]
+    assert argv == ["--arch", e["arch"], "--preset", e["preset"],
+                    "--remat", e["remat"], "--steps", str(traffic["steps"]),
+                    "--batch", str(traffic["batch"]),
+                    "--seq-len", str(traffic["seq_len"]),
+                    "--save-every", "0", "--log-every", "0",
+                    "--ckpt-dir", "/ck"]
+    assert train.tokens_per_step(traffic) == (traffic["batch"]
+                                              * traffic["seq_len"])
+    assert traffic["warmup_steps"] > train.COMPARED_STEPS
+    assert traffic["batch"] % traffic["reference_block"] == 0
+    dims = model_reference.dims_of(config)
+    layout = train.Layout(config["layout"], model_reference.shapes(dims))
+    assert set(layout.offset) == set(model_reference.shapes(dims))
+    assert set(config["limits"]) == set(train.NUMBERS)
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in BENCH["workloads"]])
+def test_each_cell_reports_its_kinds_end_to_end_metrics(name):
+    """The end-to-end entries that cover a cell are those its kind
+    reports; every cell reports setup_s and one more."""
+    _, config, _ = cell.load_cell(name)
+    listed = {m["name"] for m in BENCH["end_to_end"]
+              if name in m.get("workloads", [name])}
+    assert listed == set(run.kind_of(config).END_TO_END)
+    assert "setup_s" in listed and len(listed) >= 2
 
 
 def test_unknown_cell_is_refused():

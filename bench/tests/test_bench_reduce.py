@@ -129,3 +129,49 @@ def test_profile_window_starts_when_the_device_is_traced(tmp_path):
     assert 0 < lo < hi
     assert hi - lo >= 50_000_000
     assert list((tmp_path / "profile").rglob("*.xplane.pb"))
+
+
+def test_traced_run_whose_profile_caught_no_device(tmp_path):
+    """A profile with no device plane (the CPU's) gives no device numbers,
+    and the readers of the program's spans still read: the replay's
+    roofline takes its sizes from the observed traces, not the profile."""
+    import numpy as np
+    from bench import run
+    from bench.kinds import sweep
+
+    prof = run.Profile(tmp_path / "profile", lead_s=0.0, span_s=0.05)
+    prof.start()
+    prof.join()
+    assert run.device_trace(prof, measure.SPAN_NAMES,
+                            measure.KERNEL_MODULES) is None
+    assert not (tmp_path / "profile").exists()
+
+    geo = {"32K-256K": [("L1", 32768, 4, 4, 8)]}
+    replay = _span("a", "accel.replay_batch", 1_000, 20_000, parent="c")
+    replay["attrs"] = {"n_accesses": 100}
+    cache = _span("c", "cache.replay_batch", 0, 30_000, parent="r")
+    cache["attrs"] = {"workload": "KM"}
+    observed = {"traces": {("KM", "32K-256K"): (np.zeros(100),)},
+                "selections": {}}
+    m = sweep.measurements([_span("r", "dse.run", 0, 4_000_000), cache,
+                            replay], 2, None, observed, None, geo,
+                           "TPU v5 lite")
+    readers = run.metric_readers(("replay_roofline", "replay_us_per_step",
+                                  "place_roofline", "device_idle_share"))
+    ops, nbytes = kernels.replay_cost(100, list(geo.values()))
+    assert readers["replay_roofline"](m) == pytest.approx(
+        100 * (nbytes / 819e9) / 20e-6)
+    assert readers["replay_us_per_step"](m) == pytest.approx(20e-6 / 128 * 1e6)
+    assert readers["place_roofline"](m) is None
+    assert readers["device_idle_share"](m) is None
+    assert m.busy_s is None and m.breakdown is None
+
+
+def test_roofline_of_a_workload_with_no_size_is_none():
+    replay = _span("a", "accel.replay_batch", 1_000, 20_000, parent="c")
+    replay["attrs"] = {"n_accesses": 100}
+    cache = _span("c", "cache.replay_batch", 0, 30_000, parent="r")
+    cache["attrs"] = {"workload": "KM"}
+    m = measure.Measurements([_span("r", "dse.run", 0, 4_000_000), cache,
+                              replay], 2, None, peak("TPU v5 lite"), {}, {})
+    assert m.roofline("replay") is None
