@@ -7,10 +7,9 @@ command line the configuration (``entry``) and the traffic mix (``batch``,
 ``seq_len``, ``steps``) give.  What the benchmark makes, it hands the
 entry for the length of the run:
 
-* the weights: the plain reference's published initialisation
-  (``bench/model_reference.py``) drawn from ``--seed``, rounded to the
-  type they are served in, made on the device in the entry's own jitted
-  init (the entry's init key is the seed's);
+* the weights: the plain reference's published initialisation drawn from
+  ``--seed``, rounded to the type they are served in, made on the device
+  in the entry's own jitted init (the entry's init key is the seed's);
 * the batches: uniform token ids drawn from ``--seed`` and the step
   (:class:`Feed`), in place of the entry's synthetic stream;
 * a look at the state after each step, which reads the leaf norms the
@@ -23,9 +22,8 @@ and closes with the first step done ``--seconds`` later.
 
 After the window, with the program's state freed, the plain reference
 runs the compared steps from the same weights on the same batches, in
-float32 at ``HIGHEST`` precision, a block of sequences at a time
-(:class:`bench.model_reference.Trainer`).  The numbers compared, each
-against the configuration's limit:
+float32 at ``HIGHEST`` precision, a block of sequences at a time.  The
+numbers compared, each against the configuration's limit:
 
 ``loss_gap``         the largest relative gap of a compared step's loss;
 ``grad_gap``         over leaves, the gap between the norms of the first
@@ -44,6 +42,39 @@ against the configuration's limit:
 ``window_compiles``  compilations inside the window (limit 0).
 
 A leaf is one layer's slice of a stacked parameter, or a whole other one.
+
+The reference.  The configuration's ``reference`` names a module under
+``bench/`` (:func:`reference_of`), the one source of the cell's sizes,
+parameters and FLOPs; the harness reaches the architecture through it
+alone.  It imports nothing of the program, takes what every architecture
+shares from :mod:`bench.model_common`, and provides:
+
+``dims_of(config)``      the sizes the configuration file states, as a
+                         dict with at least ``V`` (the vocabulary) and
+                         ``L`` (the layers);
+``shapes(dims)``         each parameter's name and shape;
+``init(key, dims)``      the published initialisation, float32;
+``store(x, offset, dtype)``  a value as the parameter store keeps it;
+``leaf_norms(tree)``     each parameter's norm, a vector over layers for
+                         one that the reference stacks over layers on its
+                         leading axis (its own rule, not the harness's);
+``flat_leaves(norms)``   those norms on the host as ``{leaf: norm}``;
+``Trainer``              :class:`bench.model_common.Trainer` around the
+                         reference's loss: ``Trainer(dims, hp, storage,
+                         block, mode=, causal=, skip_layer=)`` and
+                         ``.steps(make_params, batches, total_steps,
+                         lr_scale=)``;
+``ROUNDING``             the controls' roundings by ``mode``;
+``train_flops_per_token(dims, seq_len)``  model FLOPs of a training step
+                         a token, forward and backward, no recomputation:
+                         what ``mfu`` counts.
+
+The configuration's ``layout`` maps each program leaf (``a/b/c``) to a
+reference parameter (reshaped) and the offset the program holds it from;
+``scopes`` (named scopes of the step, ``jax.named_scope``) and ``kernels``
+(the names that a kernel's custom calls start with) say what a traced
+run reads from the device trace for the per-layer readers
+(:class:`Measurements`); both may be left out.
 """
 from __future__ import annotations
 
@@ -55,7 +86,7 @@ import shutil
 import statistics
 import sys
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence, Tuple
 from unittest import mock
 
 import numpy as np
@@ -138,12 +169,15 @@ class Layout:
     """The program's parameter tree against the reference's names: each
     program leaf (``a/b/c``) is a reference parameter reshaped, held as
     its offset from ``offset`` (norm gains the program keeps as
-    ``gain - 1``), as the configuration's ``layout`` says."""
+    ``gain - 1``), as the configuration's ``layout`` says.  ``ref`` is
+    the reference module, ``dims`` its sizes."""
 
-    def __init__(self, layout: Dict[str, list], ref_shapes: Dict):
+    def __init__(self, layout: Dict[str, list], ref, dims: Dict):
+        self.ref = ref
+        self.shapes = ref.shapes(dims)
         self.of = {path: (name, float(off))
                    for path, (name, off) in layout.items()}
-        missing = set(ref_shapes) - {n for n, _ in self.of.values()}
+        missing = set(self.shapes) - {n for n, _ in self.of.values()}
         if missing:
             raise ValueError(f"layout leaves out {sorted(missing)}")
         self.offset = {n: o for n, o in self.of.values()}
@@ -156,7 +190,7 @@ class Layout:
         """Program leaves from the reference's, in the template's shapes
         and types; ``exact``: their values in float32."""
         import jax
-        from bench.model_reference import rounded
+        from bench.model_common import rounded
 
         def leaf(keys, t):
             p = self.path(keys)
@@ -169,21 +203,22 @@ class Layout:
         return jax.tree_util.tree_map_with_path(leaf, template)
 
     def norms(self, tree) -> Dict:
-        """The reference's leaf norms (:func:`leaf_norms`) of a program
-        tree, keyed by reference name."""
+        """The reference's leaf norms of a program tree, each leaf in its
+        reference parameter's shape, keyed by reference name."""
         import jax
-        from bench import model_reference as ref
 
         flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-        named = {self.of[self.path(k)][0]: v for k, v in flat}
-        stacked = {n: v.reshape(v.shape[0], -1) if n.startswith("layers.")
-                   else v.reshape(-1) for n, v in named.items()}
-        return ref.leaf_norms(stacked)
+        named = {}
+        for keys, v in flat:
+            name = self.of[self.path(keys)][0]
+            named[name] = v.reshape(self.shapes[name])
+        return self.ref.leaf_norms(named)
 
 
 class Watcher:
     """The entry's ``on_step`` and the look at its state after each step:
-    the window's clock, the compared steps' losses, the state's leaf
+    the window's clock, the compared steps' losses, the step's other
+    metrics over the window's steps (``counters``), the state's leaf
     norms after the first and the last compared step, and (traced) the
     benchmark's spans on the profiler's clock."""
 
@@ -202,6 +237,7 @@ class Watcher:
         # and the process's CPU use across the window (for the log)
         self.ends: List[float] = []
         self.usage = []
+        self.counters: Dict[str, List[float]] = {}
         self._span = None
 
     def annotate(self, name: Optional[str]) -> None:
@@ -224,6 +260,10 @@ class Watcher:
             self.losses.append(loss)
         if step >= self.warmup - 1:
             self.ends.append(now)
+        if step >= self.warmup:
+            for k, v in metrics.items():
+                if k != "loss":
+                    self.counters.setdefault(k, []).append(v)
         if step == self.warmup - 1:
             self.t_open, self.open_compiles = now, self.watch.count
             self.usage.append(resource.getrusage(resource.RUSAGE_SELF))
@@ -236,12 +276,16 @@ class Watcher:
             self.annotate(None)
             raise WindowClosed(step)
 
+    def step_times(self) -> np.ndarray:
+        """The host-clock seconds of each window step."""
+        return np.diff(self.ends)
+
     def step_log(self) -> str:
         """The window's step times and the process's CPU use across it:
         what tells a slow window's stalls from a slower chip."""
         if len(self.ends) < 2 or len(self.usage) < 2:
             return "no window steps"
-        dt = np.diff(self.ends)
+        dt = self.step_times()
         med = float(np.median(dt))
         slow = dt > 1.2 * med
         a, b = self.usage
@@ -259,14 +303,23 @@ class Watcher:
 
 
 class Measurements(DeviceReadings):
-    """What a traced train run measured, for the per-layer readers."""
+    """What a traced train run measured, for the per-layer readers: the
+    window's model FLOPs, seconds and step times on the host clock, the
+    mean of each step metric over the window's steps, and the profiled
+    part's device trace (None where the profile caught no device) with
+    the time under each of the configuration's ``scopes`` and the ops of
+    each of its ``kernels``."""
 
     def __init__(self, device: Optional[Dict], model_flops: float,
-                 host_window_s: float, peaks: Dict[str, float]):
+                 host_window_s: float, peaks: Dict[str, float],
+                 step_s: Sequence[float] = (),
+                 counters: Optional[Dict[str, List[float]]] = None):
         self.device = device
         self.model_flops = model_flops
         self.host_window_s = host_window_s
         self.peaks = peaks
+        self.step_s = list(step_s)
+        self.counters = counters or {}
 
     def mfu(self) -> Optional[float]:
         """Model FLOPs of the window's steps over the window and the
@@ -276,8 +329,44 @@ class Measurements(DeviceReadings):
         return (100.0 * self.model_flops
                 / (self.host_window_s * self.peaks["flops_per_s"]))
 
+    def step_median_s(self) -> Optional[float]:
+        """The median of the window's step times, in seconds."""
+        return statistics.median(self.step_s) if self.step_s else None
+
+    def counter(self, name: str) -> Optional[float]:
+        """The mean of the step metric ``name`` over the window's steps."""
+        seen = self.counters.get(name)
+        return statistics.fmean(seen) if seen else None
+
+    def scope_s(self, scope: str) -> Optional[float]:
+        """Device seconds under the named scope ``scope`` over the
+        profiled part: the union of its ops' intervals, averaged over the
+        chips."""
+        if self.device is None:
+            return None
+        return self.device["scopes"].get(scope)
+
+    def scope_s_per_step(self, scope: str) -> Optional[float]:
+        """:meth:`scope_s` a step: over the profiled part's length in
+        window median steps."""
+        secs, med = self.scope_s(scope), self.step_median_s()
+        if secs is None or med is None or not self.window_s:
+            return None
+        return secs * med / self.window_s
+
+    def ops(self, prefix: str) -> List[Tuple[float, str]]:
+        """``(device seconds, HLO head)`` of each whole custom-call op in
+        the profiled part whose name starts with ``prefix``, one of the
+        configuration's ``kernels``."""
+        if self.device is None:
+            return []
+        return [tuple(o) for o in self.device["ops"].get(prefix, [])]
+
 
 def reference_of(config: Dict):
+    """The configuration's reference module, ``bench.<reference>``: the
+    harness's one way to the architecture (the contract is in this
+    module's docstring)."""
     import importlib
     return importlib.import_module(f"bench.{config['reference']}")
 
@@ -299,7 +388,7 @@ def drive(config: Dict, traffic: Dict, seed: int, seconds: float, watch,
 
     ref = reference_of(config)
     dims = ref.dims_of(config)
-    layout = Layout(config["layout"], ref.shapes(dims))
+    layout = Layout(config["layout"], ref, dims)
     b1 = config["optimizer"]["beta1"]
     real_init = steps_mod.init_train_state
     key = seed_key(seed)
@@ -382,13 +471,13 @@ def reference_readings(config: Dict, traffic: Dict, seed: int,
                        ) -> Dict:
     """The plain reference's readings of the compared steps from the
     seed's weights and batches; ``mode`` (a control's rounding,
-    :data:`bench.model_reference.ROUNDING`) and ``fault`` put a control or
+    the reference's ``ROUNDING``) and ``fault`` put a control or
     a planted fault in its place (``half_batch``, ``double_update``,
     ``skip_layer``, ``no_causal_mask``)."""
     import jax
     ref = reference_of(config)
     dims = ref.dims_of(config)
-    layout = Layout(config["layout"], ref.shapes(dims))
+    layout = Layout(config["layout"], ref, dims)
     storage = storage_of(config, layout)
     key = seed_key(seed)
     make = jax.jit(lambda k: {n: ref.store(x, *storage[n])
@@ -454,7 +543,6 @@ def run(ctx):
     """One run of a train cell."""
     from bench import cell as cells
     from bench.kinds import Outcome
-    from bench.model_flops import train_flops_per_token
     from bench.peaks import peak
 
     args, config, traffic, log = ctx.args, ctx.config, ctx.traffic, ctx.log
@@ -489,12 +577,15 @@ def run(ctx):
 
     m = None
     if args.trace:
-        from bench import model_reference
-        dims = model_reference.dims_of(config)
-        device = ctx.device_trace(profile, SPAN_NAMES, {})
-        m = Measurements(device, tokens * train_flops_per_token(
-            dims, traffic["seq_len"]), window_s,
-            peak(ctx.devices[0].device_kind))
+        ref_mod = reference_of(config)
+        flops = ref_mod.train_flops_per_token(ref_mod.dims_of(config),
+                                              traffic["seq_len"])
+        device = ctx.device_trace(profile, SPAN_NAMES, {},
+                                  config.get("scopes", ()),
+                                  config.get("kernels", ()))
+        m = Measurements(device, tokens * flops, window_s,
+                         peak(ctx.devices[0].device_kind),
+                         w.step_times(), w.counters)
     end_to_end = {
         "tokens_per_s": {"value": tokens / window_s, "unit": "tokens/s"},
         "peak_rss_gb": {"value": rss_gb, "unit": "GB"},

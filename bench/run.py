@@ -41,7 +41,6 @@ import threading
 from typing import Dict, Optional, Sequence
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
-METRICS = ROOT / "bench" / "metrics"
 
 
 def log(*parts) -> None:
@@ -89,12 +88,14 @@ def devices_or_exit(chips: int, require_tpu: bool = True):
     return devices[:chips]
 
 
-def metric_readers(names):
-    """The per-layer metric readers, one file each under bench/metrics/."""
+def metric_readers(names, root: pathlib.Path = ROOT):
+    """The per-layer metric readers, one file each under
+    ``<root>/bench/metrics/``."""
     out = {}
     for name in names:
         spec = importlib.util.spec_from_file_location(
-            f"bench_metric_{name.replace('.', '_')}", METRICS / f"{name}.py")
+            f"bench_metric_{name.replace('.', '_')}",
+            root / "bench" / "metrics" / f"{name}.py")
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         out[name] = mod.read
@@ -130,7 +131,8 @@ class Profile(threading.Thread):
 
 
 def device_trace(profile: Optional[Profile], span_names: Sequence[str],
-                 kernel_modules: Dict[str, str]) -> Optional[Dict]:
+                 kernel_modules: Dict[str, str], scopes: Sequence[str] = (),
+                 op_prefixes: Sequence[str] = ()) -> Optional[Dict]:
     """The profiled part of the window reduced (:func:`bench.devtrace.reduce`),
     or None where nothing was profiled or the profile caught no device.
     The profile's files are removed."""
@@ -149,7 +151,7 @@ def device_trace(profile: Optional[Profile], span_names: Sequence[str],
             for name, _, _ in lines[devtrace.MODULES_LINE]:
                 head = name.split("(", 1)[0]
                 mods[head] = mods.get(head, 0) + 1
-            starts = [s for v in lines.values() for _, s, _ in v]
+            starts = [e[1] for v in lines.values() for e in v]
             log(f"{chip}: lines {ev['lines'].get(chip)}; "
                 + ", ".join(f"{k} {len(v)}" for k, v in lines.items())
                 + f"; events from {min(starts, default=None)} to "
@@ -161,7 +163,11 @@ def device_trace(profile: Optional[Profile], span_names: Sequence[str],
     if not ev["chips"]:
         log("the profile holds no TPU device plane")
         return None
-    return devtrace.reduce(ev, profile.window_ns, kernel_modules)
+    out = devtrace.reduce(ev, profile.window_ns, kernel_modules, scopes,
+                          op_prefixes)
+    for name, path in out["top_paths"]:
+        log(f"top op {name[:60]!r}: path {path!r}")
+    return out
 
 
 def kind_of(config: Dict):
@@ -211,7 +217,7 @@ def run(args, require_tpu: bool = True, root: pathlib.Path = ROOT) -> dict:
         m = res.measurements
         device["busy_s"], device["window_s"] = m.busy_s, m.window_s
         breakdown = m.breakdown
-        readers = metric_readers(e["name"] for e in per_layer)
+        readers = metric_readers((e["name"] for e in per_layer), root)
         metrics = {}
         for entry in per_layer:
             value = readers[entry["name"]](m)

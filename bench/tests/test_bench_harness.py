@@ -35,9 +35,9 @@ def test_cells_find_their_config_and_traffic(name):
 
 
 def _train_cell_holds(config, traffic):
-    """The entry's command line, the tokens of a step, and the layout
-    that ties the program's parameters to the reference's."""
-    from bench import model_reference
+    """The entry's command line, the tokens of a step, the layout that
+    ties the program's parameters to those of the configuration's
+    reference module, and the reference's FLOP count."""
     from bench.kinds import train
     argv = train.argv_of(config, traffic, "/ck")
     e = config["entry"]
@@ -51,9 +51,11 @@ def _train_cell_holds(config, traffic):
                                               * traffic["seq_len"])
     assert traffic["warmup_steps"] > train.COMPARED_STEPS
     assert traffic["batch"] % traffic["reference_block"] == 0
-    dims = model_reference.dims_of(config)
-    layout = train.Layout(config["layout"], model_reference.shapes(dims))
-    assert set(layout.offset) == set(model_reference.shapes(dims))
+    ref = train.reference_of(config)
+    dims = ref.dims_of(config)
+    layout = train.Layout(config["layout"], ref, dims)
+    assert set(layout.offset) == set(ref.shapes(dims))
+    assert ref.train_flops_per_token(dims, traffic["seq_len"]) > 0
     assert set(config["limits"]) == set(train.NUMBERS)
 
 

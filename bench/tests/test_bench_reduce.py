@@ -4,7 +4,7 @@ from __future__ import annotations
 
 import pytest
 
-from bench import devtrace, kernels, measure, spans
+from bench import devtrace, kernels, measure, spans, xspace
 from bench.peaks import peak
 
 
@@ -64,6 +64,62 @@ def test_device_reduction_by_hand():
     # its midpoint (50: cache.select|BFS opened last)
     assert got["idle_gaps"] == [["cache.select|BFS", pytest.approx(20e-9)],
                                 ["engine.warm", pytest.approx(10e-9)]]
+
+
+def _scoped_trace():
+    # a while op (0-50) under "moe" holding two body ops under it (10-20,
+    # 30-45); a backward op under "moe" (60-70); an attention op (50-60);
+    # an op with no path (70-80); a "moe" op past the window end (95-120);
+    # two custom calls, one wholly inside the window and one past its end
+    ops = [("%while.3 = (s32[]) while(...)", 0, 50, "jit(step)/jvp(moe)/while"),
+           ("%fusion.1 = f32[8]", 10, 10,
+            "jit(step)/jvp(moe)/while/body/closed_call/dot_general"),
+           ("%fusion.2 = f32[8]", 30, 15,
+            "jit(step)/jvp(moe)/while/body/tanh"),
+           ("%fusion.3 = f32[8]", 50, 10, "jit(step)/jvp(attn)/dot_general"),
+           ("%fusion.4 = f32[8]", 60, 10,
+            "jit(step)/transpose(jvp(moe))/dot_general"),
+           ("%copy.5 = f32[8]", 70, 10, None),
+           ("%fusion.6 = f32[8]", 95, 25, "jit(step)/jvp(moe)/add"),
+           ("%flash.7 = f32[8,128]{1,0} custom-call(f32[8,128]{1,0} %p), "
+            "custom_call_target=\"tpu_custom_call\"", 80, 5,
+            "jit(step)/attn/flash"),
+           ("%flash.8 = f32[8,128]{1,0} custom-call(f32[8,128]{1,0} %p), "
+            "custom_call_target=\"tpu_custom_call\"", 92, 10,
+            "jit(step)/attn/flash")]
+    return {"chips": {"/device:TPU:0": {"XLA Ops": ops, "XLA Modules": []}},
+            "host": []}
+
+
+def test_scope_time_is_the_union_of_its_ops_in_the_window():
+    got = devtrace.reduce(_scoped_trace(), (0, 100), {},
+                          scopes=("moe", "attn", "none"),
+                          op_prefixes=("flash", "fusion"))
+    # moe: the while and its body ops once (0-50), the backward op
+    # (60-70) and the cut op past the window (95-100); attn: 50-60 and
+    # the flash calls 80-85 and 92-100, cut
+    assert got["scopes"]["moe"] == pytest.approx(65e-9)
+    assert got["scopes"]["attn"] == pytest.approx(23e-9)
+    # a scope with no op in the window reads nothing, not 0
+    assert "none" not in got["scopes"]
+    # busy 0-85 and 92-100: the op with no path counts there alone
+    assert got["busy_s"] == pytest.approx(93e-9)
+    # only the custom call wholly inside the window, without its
+    # attributes; fusions are no custom calls
+    assert got["ops"] == {"flash": [[pytest.approx(5e-9),
+                                     "%flash.7 = f32[8,128]{1,0} "
+                                     "custom-call(f32[8,128]{1,0} %p)"]],
+                          "fusion": []}
+    assert got["top_paths"][0] == ["%while.3 = (s32[]) while(...)",
+                                   "jit(step)/jvp(moe)/while"]
+
+
+def test_a_scope_is_a_whole_part_of_the_path():
+    assert devtrace.in_scope("jit(f)/transpose(jvp(moe))/dot", "moe")
+    assert devtrace.in_scope("jit(f)/eva_cim.place/sort", "eva_cim.place")
+    assert not devtrace.in_scope("jit(f)/moe_router/dot", "moe")
+    assert not devtrace.in_scope("jit(f)/jvp(attn)/dot", "jit")
+    assert not devtrace.in_scope(None, "moe")
 
 
 def test_reduction_refuses_a_trace_without_a_device():
@@ -175,3 +231,118 @@ def test_roofline_of_a_workload_with_no_size_is_none():
     m = measure.Measurements([_span("r", "dse.run", 0, 4_000_000), cache,
                               replay], 2, None, peak("TPU v5 lite"), {}, {})
     assert m.roofline("replay") is None
+
+
+def test_device_trace_reads_a_device_plane_with_name_paths(tmp_path,
+                                                           monkeypatch):
+    """``run.device_trace`` over a profile that holds a device plane: its
+    ops carry name paths beside their times, its modules none."""
+    from bench import run
+
+    prof = run.Profile(tmp_path / "profile", lead_s=0.0, span_s=0.0)
+    prof.path.mkdir()
+    (prof.path / "host.xplane.pb").write_bytes(b"")
+    prof.window_ns = (0, 100)
+    ev = _scoped_trace()
+    ev["chips"]["/device:TPU:0"]["XLA Modules"] = [
+        ("jit_step(1)", 0, 90)]
+    ev["lines"] = {"/device:TPU:0": ["XLA Modules", "XLA Ops"]}
+    monkeypatch.setattr(devtrace, "events_of", lambda path, names: ev)
+    got = run.device_trace(prof, (), {}, ("moe",), ("flash",))
+    assert got["scopes"]["moe"] == pytest.approx(65e-9)
+    assert got["busy_s"] == pytest.approx(93e-9)
+    assert not prof.path.exists()
+
+
+def test_device_trace_reads_the_events_of_a_real_profile(tmp_path,
+                                                         monkeypatch):
+    """``run.device_trace`` over a profile the profiler wrote, its ops
+    read as the CPU client's thread line stands in for a chip's ``XLA
+    Ops``: each op finds its name path in its module's HLO, which the
+    profile keeps, and a named scope's ops count under it."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+    from bench import run
+
+    @jax.jit
+    def f(x):
+        with jax.named_scope("probe_scope"):
+            x = jnp.sin(x) @ x
+        return jnp.cos(x)
+
+    x = jnp.ones((128, 128), jnp.float32)
+    f(x).block_until_ready()
+    prof = run.Profile(tmp_path / "profile", lead_s=0.0, span_s=0.3)
+    prof.start()
+    while prof.window_ns is None:
+        f(x).block_until_ready()
+    prof.join()
+    pd = ProfileData.from_file(str(next(prof.path.rglob("*.xplane.pb"))))
+    client = [line.name for plane in pd.planes
+              if plane.name == devtrace.HOST_PLANE for line in plane.lines
+              if line.name.startswith("tf_XLAPjRtCpuClient")]
+    if not client:
+        pytest.skip("this jaxlib's CPU profile has no client thread line")
+    monkeypatch.setattr(devtrace, "DEVICE_PREFIX", devtrace.HOST_PLANE)
+    monkeypatch.setattr(devtrace, "OPS_LINE", client[0])
+    got = run.device_trace(prof, (), {}, ("probe_scope",))
+    paths = [p for _, p in got["top_paths"] if p is not None]
+    assert got["busy_s"] > 0 and paths
+    assert any(devtrace.in_scope(p, "probe_scope") for p in paths)
+    assert 0 < got["scopes"]["probe_scope"] <= got["busy_s"]
+    assert not prof.path.exists()
+
+
+def _pb(field, value):
+    """One protobuf field: a varint for an int, else length-delimited."""
+    def varint(n):
+        out = b""
+        while True:
+            out += bytes([(n & 0x7F) | (0x80 if n > 0x7F else 0)])
+            n >>= 7
+            if not n:
+                return out
+    if isinstance(value, int):
+        return varint(field << 3) + varint(value)
+    value = value.encode() if isinstance(value, str) else value
+    return varint(field << 3 | 2) + varint(len(value)) + value
+
+
+def _hlo(instructions):
+    comp = b"".join(_pb(2, _pb(1, name) + (_pb(7, _pb(2, path)) if path
+                                          else b""))
+                    for name, path in instructions)
+    return _pb(1, _pb(1, "m") + _pb(3, _pb(1, "entry") + comp))
+
+
+def _xspace(modules):
+    """An XSpace whose metadata plane holds each module's HloProto under
+    the stat ``Hlo Proto`` (stat metadata id 7), after a device plane."""
+    entries = b"".join(
+        _pb(4, _pb(1, i + 1) + _pb(2, _pb(1, i + 1) + _pb(2, name)
+                                   + _pb(5, _pb(1, 7) + _pb(6, _hlo(ins)))))
+        for i, (name, ins) in enumerate(modules.items()))
+    meta = (_pb(1, 9) + _pb(2, xspace.METADATA_PLANE) + entries
+            + _pb(5, _pb(1, 7) + _pb(2, _pb(1, 7) + _pb(2, "Hlo Proto"))))
+    device = _pb(2, "/device:TPU:0") + _pb(3, _pb(2, "XLA Ops"))
+    return _pb(1, device) + _pb(1, meta)
+
+
+def test_op_paths_come_from_the_module_that_ran_the_op():
+    data = _xspace({"jit_step(3)": [("fusion.1", "jit(step)/jvp(moe)/dot"),
+                                    ("copy.2", None),
+                                    ("while.4", "jit(step)/while")],
+                    "jit_init(5)": [("fusion.1", "jit(init)/attn/add")]})
+    assert set(xspace.hlo_modules(data)) == {"jit_step(3)", "jit_init(5)"}
+    find = xspace.path_finder(data)
+    event = "%fusion.1 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop"
+    assert find("jit_step(3)", event) == "jit(step)/jvp(moe)/dot"
+    assert find("jit_init(5)", event) == "jit(init)/attn/add"
+    # with no module known, only an instruction of one module finds it
+    assert find(None, event) is None
+    assert find(None, "%while.4 = (s32[]) while(...)") == "jit(step)/while"
+    assert find("jit_step(3)", "%copy.2 = f32[8]{0} copy(...)") is None
+    # a module left out is not read
+    only = xspace.path_finder(data, lambda m: m.startswith("jit_init"))
+    assert only(None, event) == "jit(init)/attn/add"

@@ -2,13 +2,14 @@
 (the look for a chip is skipped) at the smoke preset, ``correct`` holds
 for a sound run and fails for each fault the cell can have and for the
 controls (the reference in int8, float8 e4m3 or float8 e5m2); a second
-model configuration needs only
-files of its own.
+architecture needs only files of its own: a configuration, a traffic mix,
+a reference module and its readers.
 
 The cells run in a benchmark root of their own: the qwen1.5-0.5b
 configuration at the smoke preset's sizes (2 layers, width 64, 4 heads of
-16, vocabulary 512) and yi-34b's smoke preset (untied head, no biases),
-4 x 64 tokens a step.  Their limits hold the smoke sizes' readings on the
+16, vocabulary 512) and yi-34b's smoke preset (untied head, no biases)
+against a reference of its own (``bench/tests/toy_reference.py``), with a
+traffic mix and a per-layer reader of its own, 4 x 64 tokens a step.  Their limits hold the smoke sizes' readings on the
 CPU (a sound run reads ``loss_gap`` under 2e-5, ``grad_gap`` under 4e-3
 and ``update_gap`` under 0.07; the int8 control ``grad_gap`` 0.09 and
 more), not the cell's.
@@ -23,8 +24,10 @@ import types
 import numpy as np
 import pytest
 
-from bench import model_flops, model_reference, run
+from bench import model_reference, run
 from bench.kinds import train
+from bench.tests import toy_reference
+from bench.tests.test_bench_harness import _train_cell_holds
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 LIMITS = {"loss_gap": 1e-4, "grad_gap": 0.02, "update_gap": 0.12,
@@ -34,15 +37,30 @@ SMOKE = {"hidden_size": 64, "num_attention_heads": 4,
          "vocab_size": 512, "num_hidden_layers": 2}
 
 
+# yi-34b's program leaves against the toy reference's names
+YI_LAYOUT = {"embed": ["token_embd", 0], "lm_head": ["output", 0],
+             "final_norm/w": ["output_norm", 1],
+             "layers/attn_norm/w": ["blk.attn_norm", 1],
+             "layers/ffn_norm/w": ["blk.ffn_norm", 1],
+             "layers/attn/wq": ["blk.attn_q", 0],
+             "layers/attn/wk": ["blk.attn_k", 0],
+             "layers/attn/wv": ["blk.attn_v", 0],
+             "layers/attn/wo": ["blk.attn_output", 0],
+             "layers/ffn/w_gate": ["blk.ffn_gate", 0],
+             "layers/ffn/w_up": ["blk.ffn_up", 0],
+             "layers/ffn/w_down": ["blk.ffn_down", 0]}
+# the yi cell's own per-layer reader, a file of its own in its root
+YI_READER = '''def read(m):
+    return m.counter("grad_norm")
+'''
+
+
 def _yi(qwen: dict) -> dict:
-    """yi-34b's smoke preset in the same form: a llama decoder."""
-    cfg = dict(qwen, name="yi-smoke", qkv_bias=False,
-               tie_word_embeddings=False, rope_theta=5e6,
-               entry=dict(qwen["entry"], arch="yi-34b"))
-    layout = {k: v for k, v in qwen["layout"].items()
-              if not k.startswith("layers/attn/b")}
-    layout["lm_head"] = ["lm_head", 0]
-    cfg["layout"] = layout
+    """yi-34b's smoke preset: a llama decoder, against the toy reference."""
+    cfg = {k: v for k, v in qwen.items() if k != "qkv_bias"}
+    cfg.update(name="yi-smoke", tie_word_embeddings=False, rope_theta=5e6,
+               entry=dict(qwen["entry"], arch="yi-34b"),
+               reference="tests.toy_reference", layout=YI_LAYOUT)
     return cfg
 
 
@@ -57,22 +75,31 @@ def smoke_root(tmp_path_factory):
     traffic = json.loads((ROOT / "bench/traffic/train-s2048.json").read_text())
     traffic.update(batch=4, seq_len=64, reference_block=2,
                    profile={"lead_s": 0.0, "span_s": 0.2})
-    (root / "bench/configs").mkdir(parents=True)
-    (root / "bench/traffic").mkdir(parents=True)
+    for d in ("configs", "traffic", "metrics"):
+        (root / "bench" / d).mkdir(parents=True)
     for cfg in (qwen, _yi(qwen)):
         (root / f"bench/configs/{cfg['name']}.json").write_text(
             json.dumps(cfg))
     (root / "bench/traffic/smoke.json").write_text(json.dumps(traffic))
+    (root / "bench/traffic/yi-smoke.json").write_text(json.dumps(
+        dict(traffic, seq_len=32)))
+    for reader in (ROOT / "bench/metrics").glob("*.py"):
+        (root / "bench/metrics" / reader.name).write_text(reader.read_text())
+    (root / "bench/metrics/grad_norm_mean.py").write_text(YI_READER)
     (root / "BENCHMARK.json").write_text(json.dumps({
         "configs": [{"name": n, "file": f"bench/configs/{n}.json"}
                     for n in ("qwen-smoke", "yi-smoke")],
-        "workloads": [{"name": f"{n}.train", "config": f"{n}-smoke",
-                       "traffic": "smoke", "chips": 1}
-                      for n in ("qwen", "yi")],
+        "workloads": [{"name": "qwen.train", "config": "qwen-smoke",
+                       "traffic": "smoke", "chips": 1},
+                      {"name": "yi.train", "config": "yi-smoke",
+                       "traffic": "yi-smoke", "chips": 1}],
         "per_layer": [dict(m, workloads=["qwen.train", "yi.train"])
                       for m in json.loads((ROOT / "BENCHMARK.json")
                                           .read_text())["per_layer"]
-                      if m["moves"] == "tokens_per_s"]}))
+                      if m["moves"] == "tokens_per_s"]
+        + [{"name": "grad_norm_mean", "unit": "1", "better": "lower",
+            "source": "program_counter", "layer": "optimizer",
+            "moves": "tokens_per_s", "workloads": ["yi.train"]}]}))
     return root
 
 
@@ -133,16 +160,39 @@ def test_traced_run_reads_the_step_and_no_device_off_the_chip(
     monkeypatch.setitem(peaks.PEAKS, "cpu", peaks.PEAKS["TPU v5 lite"])
     out = drive(trace=1)
     assert out["correct"], out["checks"]
-    assert list(out["metrics"]) == ["mfu"]
+    assert list(out["metrics"]) == ["mfu", "step_ms_median"]
     assert 0 < out["metrics"]["mfu"]["value"] < 100
+    assert out["metrics"]["step_ms_median"]["value"] > 0
     assert out["device"]["busy_s"] is None and "breakdown" not in out
 
 
-def test_a_second_model_needs_only_files_of_its_own(drive):
-    """yi-34b's cell is a configuration, a traffic mix and a
-    BENCHMARK.json entry: no code of the harness names it."""
+def test_a_second_model_needs_only_files_of_its_own(drive, smoke_root,
+                                                    monkeypatch):
+    """yi-34b's cell is a configuration, a traffic mix, a reference module
+    with leaf names, stacking and a FLOP count of its own, a reader, and
+    BENCHMARK.json entries: no code of the harness names them.  Its
+    traced run counts ``mfu`` from that reference's FLOPs."""
+    from bench import cell, peaks
+    _, config, traffic = cell.load_cell("yi.train", smoke_root)
+    _train_cell_holds(config, traffic)
     out = drive("yi.train")
     assert out["correct"], out["checks"]
+
+    monkeypatch.setitem(peaks.PEAKS, "cpu", peaks.PEAKS["TPU v5 lite"])
+    out = drive("yi.train", trace=1)
+    assert out["correct"], out["checks"]
+    got = {k: v["value"] for k, v in out["metrics"].items()}
+    assert sorted(got) == ["grad_norm_mean", "mfu", "step_ms_median"]
+    assert got["grad_norm_mean"] > 0
+    # one window step: the window's seconds are its step's
+    flops = toy_reference.train_flops_per_token(
+        toy_reference.dims_of(config), traffic["seq_len"])
+    assert flops != model_reference.train_flops_per_token(
+        model_reference.dims_of(dict(config, qkv_bias=False)),
+        traffic["seq_len"])
+    assert got["mfu"] == pytest.approx(
+        100 * train.tokens_per_step(traffic) * flops
+        / (got["step_ms_median"] / 1e3 * 197e12), rel=1e-9)
 
 
 def test_state_left_unchanged_fails(drive, monkeypatch):
@@ -271,11 +321,13 @@ def test_seed_key_keeps_every_bit():
 def test_model_flops_of_the_cell():
     cfg = json.loads(
         (ROOT / "bench/configs/qwen1.5-0.5b-train.json").read_text())
-    dims = model_reference.dims_of(cfg)
+    ref = train.reference_of(cfg)
+    dims = ref.dims_of(cfg)
     per_layer = 4 * 1024 * 1024 + 3 * 1024 * 2816
-    assert model_flops.layer_weights(dims) == per_layer
-    assert model_flops.train_flops_per_token(dims, 2048) == (
+    assert ref.layer_weights(dims) == per_layer
+    assert ref.train_flops_per_token(dims, 2048) == (
         6 * 24 * per_layer + 6 * 1024 * 151936 + 12 * 24 * 1024 * 2048)
+    assert ref.train_flops_per_token(dims, 2048) == 3_387_162_624
 
 
 def test_device_peak_counts_the_reserved_scratch():
@@ -289,11 +341,54 @@ def test_device_peak_counts_the_reserved_scratch():
 
 
 def test_train_readers():
-    readers = run.metric_readers(("mfu", "device_idle_share.train"))
+    readers = run.metric_readers(("mfu", "device_idle_share.train",
+                                  "step_ms_median"))
     m = train.Measurements(None, 197e12, 2.0, {"flops_per_s": 197e12})
     assert readers["mfu"](m) == pytest.approx(50.0)
     assert readers["device_idle_share.train"](m) is None
+    assert readers["step_ms_median"](m) is None
+    assert m.scope_s("attn") is None and m.ops("flash") == []
     m.device = {"busy_s": 0.75, "window_s": 1.0, "device_ops": [],
                 "idle_gaps": []}
     assert readers["device_idle_share.train"](m) == pytest.approx(25.0)
     assert m.breakdown == {"device_ops": [], "idle_gaps": []}
+
+
+def test_step_median_and_scope_per_step():
+    """The median of the window's step times, one stalled step left out
+    by it, and a scope's device time over the profiled part's length in
+    median steps."""
+    readers = run.metric_readers(("step_ms_median",))
+    m = train.Measurements(
+        {"busy_s": 1.9, "window_s": 2.0, "device_ops": [], "idle_gaps": [],
+         "scopes": {"attn": 0.8},
+         "ops": {"flash": [[0.01, "%flash.1 = f32[8] custom-call()"]]}},
+        0.0, 3.4, {"flops_per_s": 197e12}, [0.5, 0.5, 1.9, 0.5])
+    assert readers["step_ms_median"](m) == pytest.approx(500.0)
+    assert m.scope_s("attn") == pytest.approx(0.8)
+    assert m.scope_s("moe") is None
+    # 2 s profiled are 4 median steps
+    assert m.scope_s_per_step("attn") == pytest.approx(0.2)
+    assert m.ops("flash") == [(0.01, "%flash.1 = f32[8] custom-call()")]
+    assert m.ops("moe") == []
+
+
+def test_counter_is_averaged_over_the_window_steps_only(monkeypatch):
+    """Step metrics other than the loss are kept from the first window
+    step through the one that closes the window; warm-up steps are left
+    out."""
+    clock = iter([0.0, 1.0, 2.0, 3.0, 4.0, 5.0])
+    monkeypatch.setattr(train.time, "perf_counter", lambda: next(clock))
+    watch = types.SimpleNamespace(count=0)
+    w = train.Watcher(1.5, 4, watch, observe=None)
+    norms = [100.0, 100.0, 100.0, 100.0, 1.0, 3.0]
+    with pytest.raises(train.WindowClosed):
+        for step, g in enumerate(norms):
+            w.on_step(step, {"loss": 2.0, "grad_norm": g, "lr": 1e-4})
+    # the window opened after step 3 (t = 3) and closed with step 5
+    assert w.last == 5
+    m = train.Measurements(None, 0.0, 2.0, {}, w.step_times(), w.counters)
+    assert m.counter("grad_norm") == pytest.approx(2.0)
+    assert m.counter("lr") == pytest.approx(1e-4)
+    assert m.counter("loss") is None and m.counter("aux_loss") is None
+    assert m.step_median_s() == pytest.approx(1.0)
