@@ -10,15 +10,35 @@ Bit-exactness with the :class:`~repro.core.cache.CacheHierarchy` oracle
 is the contract (the differential suite in ``tests/test_accel.py``
 fuzzes it).  The OrderedDict semantics map onto arrays as follows:
 
+  * **State layout** — each level is one ``(S, 3A)`` int32 buffer of set
+    rows: a set's ``A`` tags (``-1`` is an empty way), its ``A`` dirty
+    bits and its ``A`` LRU stamps side by side.
+  * **Update rule** — a step reads the row of the access's own set at
+    every level once, applies every touch of that set to the row in
+    registers (probe, demand fill, write-allocate), and writes the row
+    back once.  A cascade writeback whose victim line maps to another
+    set reads and writes that set's row; when it maps to the access's
+    own set it is folded into the held row, which the final write then
+    carries.  Rows are read before they are written and never read
+    again from the old buffer, so each write updates the carried buffer
+    in place.
+  * **Why in place on the TPU** — a row is the minor dimension of its
+    buffer, so the batched row reads (gathers) and row writes (scatters)
+    want the same layout and the compiler keeps them as single ops on
+    the loop carry.  Written one element at a time into ``(L, S, A)``
+    arrays, the reads wanted the ways minor and the scatters the sets
+    minor, and every touch cost a layout copy of the whole state
+    (``tests/test_tpu_compile.py`` pins the copy-free loop body).
   * **LRU order** — each resident way carries a monotonically increasing
     stamp ``t * K + slot``; ``t`` is the access index, ``slot`` numbers
     the python-side touch points of one access in their exact execution
     order (probes first, then the demand-fill/cascade-writeback chain of
     :meth:`CacheHierarchy._access`).  ``move_to_end`` is a fresh stamp;
-    the eviction victim is the min-stamp resident way.  Same-set
-    collisions inside one access (a cascade landing in the set a demand
-    fill is about to evict from) resolve exactly like the dict, because
-    the cascade's slot precedes the next demand fill's slot.
+    the eviction victim is the min-stamp resident way (the first free
+    way while the set has one).  Same-set collisions inside one access
+    (a cascade landing in the set a demand fill is about to evict from)
+    resolve exactly like the dict, because the touches apply to the row
+    in slot order.
   * **MSHR file** — a per-level ``(M,)`` line array with insertion
     stamps; FIFO retirement evicts the min-stamp entry.  A merge
     (line already outstanding) bumps the count in python — which is
@@ -89,35 +109,50 @@ def _build(L: int, S: int, A: int, M: int):
         ways = jnp.arange(A, dtype=jnp.int32)
         mslots = jnp.arange(M, dtype=jnp.int32)
 
-        def fill(tags, dirty, stamp, l, set_l, line, dirty_in, en, stamp_val):
-            """``_Level.fill`` at level ``l``: present -> refresh stamp and
-            OR the dirty bit; absent -> insert (LRU-evicting when full),
-            returning the dirty-victim flag + line for the cascade."""
-            row_t, row_d, row_s = tags[l, set_l], dirty[l, set_l], stamp[l, set_l]
+        def read(level, s):
+            """Set ``s`` of one level as its (tags, dirty, stamp) ways."""
+            row = level[s]
+            return row[:A], row[A:2 * A] != 0, row[2 * A:]
+
+        def write(level, s, row):
+            tags, dirty, stamp = row
+            return level.at[s].set(
+                jnp.concatenate([tags, dirty.astype(jnp.int32), stamp]))
+
+        def pick(cond, a, b):
+            return tuple(jnp.where(cond, x, y) for x, y in zip(a, b))
+
+        def fill(row, l, line, dirty_in, en, stamp_val):
+            """``_Level.fill`` at level ``l`` on one set's row: present ->
+            refresh stamp and OR the dirty bit; absent -> insert
+            (LRU-evicting when full), returning the dirty-victim flag +
+            line for the cascade."""
+            row_t, row_d, row_s = row
             present_vec = row_t == line
             present = present_vec.any()
             occ = row_t >= 0
             full = occ.sum() >= assoc[l]
             free_way = jnp.argmax(~occ & (ways < assoc[l]))
             lru_way = jnp.argmin(jnp.where(occ, row_s, BIG))
-            ins_way = jnp.where(full, lru_way, free_way)
-            way = jnp.where(present, jnp.argmax(present_vec), ins_way)
-            victim = en & ~present & full & row_d[ins_way]
-            victim_line = jnp.where(victim, row_t[ins_way], 0)
-            new_d = jnp.where(present, row_d[way] | dirty_in, dirty_in)
-            tags = tags.at[l, set_l, way].set(
-                jnp.where(en, line, row_t[way]))
-            dirty = dirty.at[l, set_l, way].set(
-                jnp.where(en, new_d, row_d[way]))
-            stamp = stamp.at[l, set_l, way].set(
-                jnp.where(en, stamp_val, row_s[way]))
-            return victim, victim_line, tags, dirty, stamp
+            ins = ways == jnp.where(full, lru_way, free_way)
+            at = en & jnp.where(present, present_vec, ins)
+            victim = en & ~present & full & (ins & row_d).any()
+            victim_line = jnp.where(victim, jnp.where(ins, row_t, 0).sum(), 0)
+            row = (jnp.where(at, line, row_t),
+                   jnp.where(at, dirty_in | (present_vec & row_d), row_d),
+                   jnp.where(at, stamp_val, row_s))
+            return victim, victim_line, row
 
         def access(carry, x):
-            tags, dirty, stamp, mlines, mstamp, wbs, memw, t = carry
+            cache, mlines, mstamp, wbs, memw, t = carry
+            cache, mlines, mstamp, wbs = (list(cache), list(mlines),
+                                          list(mstamp), list(wbs))
             line, wr, ok = x
             base = t * K
             set_l = [line % n_sets[l] for l in range(L)]
+            # every touch of an access's own set at level l works on this
+            # row; it is written back once, after the access
+            home = [read(cache[l], set_l[l]) for l in range(L)]
 
             # probe phase: first hit breaks the walk; every missed level
             # also probes its MSHR file
@@ -125,70 +160,73 @@ def _build(L: int, S: int, A: int, M: int):
             merged = jnp.bool_(False)
             service = jnp.int32(L + 1)
             for l in range(L):
-                row = tags[l, set_l[l]]
+                row_t, row_d, row_s = home[l]
                 probe = ok & ~found
-                hit_vec = row == line
+                hit_vec = row_t == line          # a set holds a line once
                 hit = probe & hit_vec.any()
-                way = jnp.argmax(hit_vec)
-                stamp = stamp.at[l, set_l[l], way].set(          # move_to_end
-                    jnp.where(hit, base + lookup_slot[l],
-                              stamp[l, set_l[l], way]))
+                home[l] = (row_t, row_d,                        # move_to_end
+                           jnp.where(hit & hit_vec, base + lookup_slot[l],
+                                     row_s))
                 miss = probe & ~hit
-                mrow = mlines[l]
+                mrow, msrow = mlines[l], mstamp[l]
                 in_flight = (mrow == line).any()
                 merged = merged | (miss & in_flight)
                 m_occ = mrow >= 0
                 m_full = m_occ.sum() >= mshrs[l]
                 m_free = jnp.argmax(~m_occ & (mslots < mshrs[l]))
-                m_fifo = jnp.argmin(jnp.where(m_occ, mstamp[l], BIG))
-                m_ins = jnp.where(m_full, m_fifo, m_free)
-                insert = miss & ~in_flight
-                mlines = mlines.at[l, m_ins].set(
-                    jnp.where(insert, line, mrow[m_ins]))
-                mstamp = mstamp.at[l, m_ins].set(
-                    jnp.where(insert, t, mstamp[l, m_ins]))
+                m_fifo = jnp.argmin(jnp.where(m_occ, msrow, BIG))
+                m_at = (miss & ~in_flight
+                        & (mslots == jnp.where(m_full, m_fifo, m_free)))
+                mlines[l] = jnp.where(m_at, line, mrow)
+                mstamp[l] = jnp.where(m_at, t, msrow)
                 service = jnp.where(hit, jnp.int32(l + 1), service)
                 found = found | hit
 
             # fill phase: allocate in every level above the service point;
             # each fill's dirty victim cascades into the next level down,
-            # falling off the last level as a DRAM write
+            # falling off the last level as a DRAM write.  A cascade may
+            # land in another set than the access's own: it reads and
+            # writes that set's row in place, and folds into ``home`` when
+            # the sets coincide (the later home write then supersedes it).
             for i in range(L):
                 en = ok & (service >= jnp.int32(i + 2))
-                flag, vline, tags, dirty, stamp = fill(
-                    tags, dirty, stamp, i, set_l[i], line,
-                    jnp.bool_(False), en, base + demand_slot[i])
-                wbs = wbs.at[i].add(flag.astype(jnp.int32))
+                flag, vline, home[i] = fill(home[i], i, line, jnp.bool_(False),
+                                            en, base + demand_slot[i])
+                wbs[i] = wbs[i] + flag.astype(jnp.int32)
                 for m in range(i + 1, L):
-                    flag, vline, tags, dirty, stamp = fill(
-                        tags, dirty, stamp, m, vline % n_sets[m], vline,
-                        jnp.bool_(True), flag, base + cascade_slot[i][m])
-                    wbs = wbs.at[m].add(flag.astype(jnp.int32))
+                    s = vline % n_sets[m]
+                    same = s == set_l[m]
+                    row = pick(same, home[m], read(cache[m], s))
+                    flag, vline, row = fill(row, m, vline, jnp.bool_(True),
+                                            flag, base + cascade_slot[i][m])
+                    cache[m] = write(cache[m], s, row)
+                    home[m] = pick(same, row, home[m])
+                    wbs[m] = wbs[m] + flag.astype(jnp.int32)
                 memw = memw + flag.astype(jnp.int32)
 
             # write-allocate: dirty the line in L1 (no LRU reorder)
-            row0 = tags[0, set_l[0]]
-            dirty = dirty.at[0, set_l[0]].set(
-                dirty[0, set_l[0]] | ((row0 == line) & ok & wr))
+            row_t, row_d, row_s = home[0]
+            home[0] = (row_t, row_d | ((row_t == line) & ok & wr), row_s)
+            cache = [write(cache[l], set_l[l], home[l]) for l in range(L)]
 
             bank = line % banks[jnp.minimum(service, jnp.int32(L)) - 1]
-            return ((tags, dirty, stamp, mlines, mstamp, wbs, memw, t + 1),
-                    (service, merged, bank))
+            return ((tuple(cache), tuple(mlines), tuple(mstamp), tuple(wbs),
+                     memw, t + 1), (service, merged, bank))
 
         def step(carry, x):
             with jax.named_scope(STEP_SCOPE):
                 return access(carry, x)
 
-        init = (jnp.full((L, S, A), -1, jnp.int32),
-                jnp.zeros((L, S, A), jnp.bool_),
-                jnp.zeros((L, S, A), jnp.int32),
-                jnp.full((L, M), -1, jnp.int32),
-                jnp.zeros((L, M), jnp.int32),
-                jnp.zeros((L,), jnp.int32),
+        empty_set = jnp.concatenate([jnp.full((A,), -1, jnp.int32),
+                                     jnp.zeros((2 * A,), jnp.int32)])
+        init = ((jnp.tile(empty_set, (S, 1)),) * L,
+                (jnp.full((M,), -1, jnp.int32),) * L,
+                (jnp.zeros((M,), jnp.int32),) * L,
+                (jnp.int32(0),) * L,
                 jnp.int32(0), jnp.int32(0))
         carry, (service, merged, bank) = lax.scan(
             step, init, (lines, is_w, valid))
-        wbs, memw = carry[5], carry[6]
+        wbs, memw = jnp.stack(carry[3]), carry[4]
         lvl = jnp.arange(1, L + 1, dtype=jnp.int32)
         hits = (valid[None, :] & (service[None, :] == lvl[:, None])).sum(1)
         misses = (valid[None, :] & (service[None, :] > lvl[:, None])).sum(1)

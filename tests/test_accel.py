@@ -222,6 +222,40 @@ def test_nb_fig14_geometries_bit_exact():
         assert rep_np.macr == rep_j.macr
 
 
+def test_replay_fig14_shape_class_bit_exact():
+    """One seeded stream under the three Fig. 14 presets in one batched
+    call, at the fig14 sweep's padded shape class: 3 geometries padded to
+    4, 4,096 sets, 8 ways, 8 MSHRs.  The stream runs in blocks of 50
+    accesses of one of three kinds: spread over a 1 MB footprint; crowding
+    24 lines into four sets of every level, so that L1's dirty victims
+    cascade into L2 and L2's dirty victims fall to memory; cycling five
+    lines through one 4-way L1 set, so that misses merge in the MSHRs."""
+    from repro.core.accel import replay
+    geos = PRESETS[:3]
+    rng = np.random.default_rng(1814)
+    n = 4000
+    stride = max(c.n_sets for geo in geos for c in geo) * 64
+    spread = rng.integers(0, 1 << 20, n)
+    crowd = rng.integers(0, 4, n) * 64 + rng.integers(0, 24, n) * stride
+    cycle = 7 * 64 + np.arange(n) % 5 * stride
+    kind = rng.integers(0, 3, n // 50).repeat(50)
+    addrs = np.choose(kind, [spread, crowd, cycle]).astype(np.int64)
+    wr = rng.random(n) < 0.4
+
+    (_, fn, args), = replay._launches(addrs, wr, geos)
+    assert fn is replay._build(2, 4096, 8, 8) and args[0].shape == (4, 2)
+    out = replay_columns_batch(addrs, wr, geos)
+    for gi, levels in enumerate(geos):
+        hier = CacheHierarchy(levels)
+        ref = hier.replay(addrs, wr)
+        lvl, hit, bank, mshr, counters = out[gi]
+        _assert_columns_equal(ref, (lvl, hit, bank, mshr))
+        assert counters == hier.counters()
+        assert counters["L1_writebacks"] > 0, counters
+        assert counters["L2_writebacks"] > 0 and counters["mem_writes"] > 0
+        assert mshr.any()
+
+
 @settings(max_examples=25, deadline=None)
 @given(st.integers(4, 24), st.integers(0, 5), st.sampled_from(_OPS),
        st.sampled_from(GEOMETRIES), st.sampled_from(CFGS))
